@@ -5,8 +5,9 @@ wall-clock for the pure-jnp oracle vs the Pallas kernel, swept over
 (B, T, H) shapes drawn from the registered scenarios (the AIP-training
 minibatch and the PPO rollout recompute of each env, agent axis folded
 into the batch the way the vmapped trainers fold it) plus one headline
-TPU-sized shape. Each row carries the TPU-v5e roofline terms for the
-kernel's analytic FLOP/byte footprint (``benchmarks/roofline.py``) —
+TPU-sized shape. Each row carries the roofline terms of the target chip
+(``roofline.TARGET_KIND``, TPU v5e) for the kernel's analytic FLOP/byte
+footprint (``benchmarks/roofline.py``) —
 ``roofline_fraction`` ≈ 1 means the fused scan would be MXU-bound on the
 target, not memory-bound.
 
@@ -100,7 +101,7 @@ def _gru_roofline(b, t, din, h, *, backward: bool):
         bytes_ *= 3
     return roofline.terms(flops=flops, bytes_accessed=bytes_,
                           collective_bytes=0.0, n_devices=1,
-                          peak_flops=roofline.PEAK_FLOPS_FP32)
+                          device_kind=roofline.TARGET_KIND)
 
 
 def _gae_roofline(b, t, *, backward: bool):
@@ -108,7 +109,7 @@ def _gae_roofline(b, t, *, backward: bool):
     bytes_ = 4.0 * 5 * b * t * (2.0 if backward else 1.0)
     return roofline.terms(flops=flops, bytes_accessed=bytes_,
                           collective_bytes=0.0, n_devices=1,
-                          peak_flops=roofline.PEAK_FLOPS_FP32)
+                          device_kind=roofline.TARGET_KIND)
 
 
 # ---------------------------------------------------------------------------
@@ -262,10 +263,15 @@ def main() -> None:
                     help="reduced shapes/iters (CI smoke)")
     args = ap.parse_args()
 
+    from repro import compile_cache
     from repro.kernels import dispatch
+    compile_cache.enable()
     decision = dispatch.resolve("on")
     record = {
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
+        "roofline_device_kind": roofline.TARGET_KIND,
         "interpret": decision.interpret,
         "note": ("kernel columns ran under the Pallas interpreter "
                  "(non-TPU backend); oracle columns and roofline terms "

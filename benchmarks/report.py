@@ -38,7 +38,8 @@ def roofline_row(rec):
              "bytes_accessed": rec["cost"]["bytes_accessed"],
              "collective_bytes": rec["collectives"]["total_bytes"]}
     t = roofline.terms(flops=e["flops"], bytes_accessed=e["bytes_accessed"],
-                       collective_bytes=e["collective_bytes"], n_devices=1)
+                       collective_bytes=e["collective_bytes"], n_devices=1,
+                       device_kind=roofline.TARGET_KIND)
     mf = rec.get("model_flops_global")
     ratio = (mf / rec["n_devices"] / e["flops"]) if mf else None
     return t, ratio

@@ -1,32 +1,52 @@
-"""Roofline arithmetic for the TPU v5e target.
+"""Roofline arithmetic against published per-chip peaks.
 
 The three terms (seconds) for one compiled step on an N-chip mesh:
 
-  compute_s    = HLO_FLOPs / (chips * PEAK_FLOPS)
-  memory_s     = HLO_bytes / (chips * HBM_BW)
-  collective_s = collective_bytes / (chips * ICI_BW)
+  compute_s    = HLO_FLOPs / (chips * peak FLOP/s)
+  memory_s     = HLO_bytes / (chips * HBM bytes/s)
+  collective_s = collective_bytes / (chips * ICI bytes/s)
 
 FLOPs/bytes come from ``compiled.cost_analysis()`` (per-device program ×
 device count is already folded in by the dry-run, which records per-device
 numbers — pass per-device values with chips=1, or totals with the mesh
 size). ``collective_bytes`` is parsed from the post-SPMD HLO by
 ``repro.launch.dryrun.collective_bytes``.
+
+Peaks live in :data:`PEAKS`, keyed by ``jax.Device.device_kind``; a
+device that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip (TPU v5e)
-PEAK_FLOPS_FP32 = PEAK_FLOPS / 2   # fp32 programs run at half the bf16 MXU rate
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link (~per chip, 1 link claimed)
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip
+# interconnect. The MXU's bf16 rate is also the ceiling of an fp32
+# matmul (which runs as several bf16 passes).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s": 1600e9 / 8},
+}
+
+# The chip the dry runs and the kernels' analytic roofline terms target
+# (TPU v5e; jax reports its device_kind as "TPU v5 lite").
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of one chip of ``device_kind``; raises for a
+    device the table does not hold."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def terms(*, flops: float, bytes_accessed: float, collective_bytes: float,
-          n_devices: int, peak_flops: float = PEAK_FLOPS) -> dict:
-    """``peak_flops`` defaults to the bf16 peak; pass ``PEAK_FLOPS_FP32``
-    when the FLOP count describes an fp32 program (the MARL kernels)."""
-    compute_s = flops / (n_devices * peak_flops)
-    memory_s = bytes_accessed / (n_devices * HBM_BW)
-    collective_s = collective_bytes / (n_devices * ICI_BW)
+          n_devices: int, device_kind: str) -> dict:
+    p = peaks(device_kind)
+    compute_s = flops / (n_devices * p["flops"])
+    memory_s = bytes_accessed / (n_devices * p["hbm_bytes_per_s"])
+    collective_s = collective_bytes / (n_devices * p["ici_bytes_per_s"])
     bottleneck = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", collective_s)), key=lambda kv: kv[1])[0]

@@ -241,7 +241,8 @@ def roofline_table(fast: bool = False):
         rec = json.load(open(fn))
         if rec.get("status") != "ok":
             continue
-        t = roofline.terms(**roofline.per_device(rec))
+        t = roofline.terms(**roofline.per_device(rec),
+                           device_kind=roofline.TARGET_KIND)
         rows.append({"label": os.path.basename(fn)[:-5],
                      "arch": rec["arch"], "shape": rec["shape"],
                      "mesh": rec["mesh"], "variant": rec.get("variant"),
@@ -293,6 +294,8 @@ def main() -> None:
                          "TensorBoard/xprof — repro.obs.trace spans "
                          "appear as TraceAnnotations)")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     names = [args.only] if args.only else list(BENCHES)
     print("name,metric,value")
     from repro.obs import trace as obs_trace

@@ -32,9 +32,11 @@ Writes ``experiments/bench/BENCH_dials_scaling.json`` — the perf
 trajectory artifact CI uploads — plus ``name,metric,value`` CSV lines on
 stdout.
 
-Shard counts > 1 need multiple XLA devices; this script forces
-``--xla_force_host_platform_device_count=<max shards>`` BEFORE importing
-jax, so it must run as its own process:
+Shard counts > 1 need that many devices: on the CPU platform this script
+forces ``<max shards>`` host devices (``jax_num_cpu_devices``, before the
+backend starts); on a TPU it uses the chips there are and rejects a
+shard count above ``len(jax.devices())``. Every row names the platform
+it was measured on. Run it as its own process:
 
     PYTHONPATH=src python -m benchmarks.scaling [--fast]
         [--shards 1,2,4,8,16] [--scenarios traffic-2x2,powergrid-ring16]
@@ -45,7 +47,9 @@ execution: for each P > 1 the script re-launches itself as P coordinated
 repro.distributed.bootstrap — each process forces max_shards/P host
 devices, so the global device count matches the single-process run) and
 merges the measured rows, labelled ``{scenario}-s{shards}-p{P}`` with a
-``processes`` column, into the same artifact. Shard counts that cannot
+``processes`` column, into the same artifact. The group children run
+with ``JAX_PLATFORMS=cpu`` (their rows say platform ``cpu``): the parent
+has already taken the chip for its own sweep. Shard counts that cannot
 be balanced over P processes are skipped; the shards=1 unfused baseline
 only exists at P=1.
 """
@@ -115,6 +119,7 @@ def _sweep(scenarios, shard_counts, *, rounds, inner, collect_steps,
     from repro.launch import variants
 
     suffix = f"-p{processes}" if processes > 1 else ""
+    platform = jax.devices()[0].platform
     rows = []
     for scenario in scenarios:
         env_name, side = variants.MARL_SCENARIOS[scenario]
@@ -167,6 +172,7 @@ def _sweep(scenarios, shard_counts, *, rounds, inner, collect_steps,
             inner_steps = cfg.aip_refresh * cfg.n_envs * \
                 cfg.rollout_steps * n                  # F * E * T * N
             row = {"label": f"{scenario}-s{shards}{suffix}",
+                   "platform": platform,
                    "scenario": scenario, "n_agents": n, "shards": shards,
                    "processes": processes, "streams": 4,
                    "fused": shards > 1,
@@ -242,6 +248,7 @@ def _stream_sweep(scenarios, streams_list, *, rounds, inner,
             cfg.rollout_steps * n
         rows.append({
             "label": f"{scenario}-streams{streams}",
+            "platform": jax.devices()[0].platform,
             "scenario": scenario, "n_agents": n, "shards": 1,
             "processes": 1, "streams": streams, "fused": False,
             "round_s": steady,
@@ -276,8 +283,11 @@ def _spawn_group(args, processes, shard_counts, rows_path) -> None:
         # shared dir: every rank writes its own telemetry-p{rank}.jsonl
         argv += ["--telemetry-dir", args.telemetry_dir]
     # children must not inherit a forced device count from the parent's
-    # own sweep: bootstrap sets their XLA_FLAGS from DIALS_LOCAL_DEVICES
+    # own sweep: bootstrap sets their XLA_FLAGS from DIALS_LOCAL_DEVICES.
+    # They run on host CPU devices: one process per chip, and the parent
+    # may already hold it.
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     procs = variants.launch_group(argv, processes=processes,
                                   local_devices=local, env=env)
     rcs = [p.wait() for p in procs]
@@ -343,6 +353,8 @@ def main() -> None:
         # applies the forced device count and joins the coordination
         # service) must run before the sweep's jax import.
         ctx = bootstrap.bootstrap(group)
+        from repro import compile_cache
+        compile_cache.enable()
         rows = _sweep(scenarios, shard_counts, rounds=rounds, inner=inner,
                       collect_steps=collect_steps,
                       processes=ctx.num_processes,
@@ -355,19 +367,24 @@ def main() -> None:
         return
 
     process_counts = sorted({int(p) for p in args.processes.split(",")})
+    from repro import compile_cache
+    compile_cache.enable()
     rows = []
     os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
     for processes in process_counts:
         if processes <= 1:
-            # in-process, exactly the historical single-process sweep;
-            # multiple shards need multiple devices — force them before
-            # jax loads
+            # in-process sweep; multiple shards need multiple devices —
+            # host CPU devices are forced before the backend starts (the
+            # setting only sizes the CPU platform), chips are what exist
+            import jax
             n_dev = max(shard_counts)
             if n_dev > 1:
-                os.environ["XLA_FLAGS"] = (
-                    os.environ.get("XLA_FLAGS", "") +
-                    f" --xla_force_host_platform_device_count={n_dev}"
-                ).strip()
+                jax.config.update("jax_num_cpu_devices", n_dev)
+            if n_dev > len(jax.devices()):
+                raise SystemExit(
+                    f"--shards up to {n_dev} needs {n_dev} devices; "
+                    f"{jax.devices()[0].platform} has "
+                    f"{len(jax.devices())}")
             from repro.obs import trace as obs_trace
             with obs_trace.profile(args.profile_dir):
                 rows.extend(_sweep(scenarios, shard_counts, rounds=rounds,

@@ -18,6 +18,7 @@ import argparse
 
 import jax
 
+from repro import compile_cache
 from repro.core import dials, influence
 from repro.envs import registry
 from repro.marl import policy, ppo
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--side", type=int, default=2,
                     help="uniform size knob (side=2 -> 4 agents)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     env_mod, env_cfg = registry.make(args.env, side=args.side, horizon=32)
     info = env_cfg.info()

@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.models import api, lm as lm_mod
 
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = registry.get(args.arch, reduced=True)
     if not spec.has_decode or spec.kind == "encdec":

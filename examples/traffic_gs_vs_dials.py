@@ -18,6 +18,7 @@ import time
 
 import jax
 
+from repro import compile_cache
 from repro.core import dials, influence
 from repro.envs import registry
 from repro.launch import variants
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--async-collect", action="store_true",
                     help="double-buffered overlapped GS collect")
     args = ap.parse_args()
+    compile_cache.enable()
 
     env_mod, env_cfg = registry.make(args.env, side=2, horizon=32)
     info = env_cfg.info()

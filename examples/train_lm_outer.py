@@ -18,6 +18,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import registry
 from repro.data import pipeline
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
+    compile_cache.enable()
 
     spec = registry.get(args.arch, reduced=True)
     cfg = spec.cfg.decoder if spec.kind == "encdec" else spec.cfg

@@ -152,20 +152,10 @@ def spare_device(n_in_use: int):
 
 
 def shard_map_nocheck(f, mesh: Mesh, *, in_specs, out_specs):
-    """Version-compat ``shard_map`` with replication checking disabled
-    (the DIALS per-shard body produces sharded-only outputs). jax moved
-    ``jax.experimental.shard_map`` (``check_rep=``) to ``jax.shard_map``
-    (``check_vma=``); support both so the pinned floor can move freely."""
-    sm = getattr(jax, "shard_map", None)
-    if callable(sm):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking disabled (the DIALS
+    per-shard body produces sharded-only outputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,9 @@ backward-scan kernels) and sit on the DIALS hot path: the
 GAE through them — resolved once per call site by
 ``repro.kernels.dispatch``.
 
-On CPU (this container) the kernels execute with ``interpret=True``; the
-BlockSpecs encode the intended TPU VMEM tiling (MXU-aligned 128-multiples).
+On TPU the kernels compile through Mosaic (``tests/test_tpu_compile.py``
+compiles the DIALS ones for a described v5e chip); on any other backend
+they execute with ``interpret=True``. ``layout.batch_major`` keeps a
+``vmap``'d agent axis out of the blocks' tiled last two dims.
 """
 from repro.kernels import dispatch, flash_attention, gae, gru, ssd  # noqa: F401

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-
 NEG_INF = -1e30
 
 
@@ -132,7 +129,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -1,11 +1,15 @@
 """GAE-λ reverse-scan Pallas kernels.
 
 Forward: the advantage recursion is strictly sequential in t but
-embarrassingly parallel over the (agents × envs) batch — on TPU that
-maps to a grid over T (reverse-indexed through the BlockSpec index map,
-so block t reads slice T-1-t) with the carry in VMEM scratch and the
-batch laid out on the 8×128 VPU lanes. One fused multiply-add per step
-instead of a scan of tiny XLA kernels.
+embarrassingly parallel over the (agents × envs) batch. Each block is a
+whole ``(T, bt)`` time-major slab — batch on the 128-wide lanes, time on
+the sublanes — and the kernel walks it T-1→0 with an in-kernel
+``fori_loop`` over single-row slices, the carry riding in vregs. One
+fused multiply-add per step instead of a scan of tiny XLA kernels.
+Blocks are the full array dims when the batch fits the VMEM budget, else
+128-multiple lane tiles over a ``"parallel"`` grid axis (the batch is
+zero-padded to a whole number of tiles), so the block layout always
+meets the TPU's (8, 128) tiling rule.
 
 Backward: the recursion is LINEAR in (r, v, nv), so the adjoint is the
 transposed recurrence — a FORWARD-time scan of the advantage cotangent
@@ -23,75 +27,94 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.layout import batch_major
+
+# Scoped VMEM the double-buffered blocks of one grid step may take: half
+# of the TPU v5e's 16 MiB default scoped limit.
+_VMEM_BUDGET = 8 * 2**20
 
 
-def _gae_kernel(r_ref, v_ref, nv_ref, d_ref, adv_ref, carry_ref, *,
+def _lane_tile(t: int, b: int, n_arrays: int) -> int:
+    """Block width over the batch: all of B while ``n_arrays``
+    double-buffered (T, B) f32 blocks fit the budget, else the widest
+    multiple of 128 lanes that does."""
+    per_lane = 2 * n_arrays * t * 4
+    if b * per_lane <= _VMEM_BUDGET:
+        return b
+    return max(128, _VMEM_BUDGET // per_lane // 128 * 128)
+
+
+def _lane_tiled_call(kernel, inputs, n_out: int, interpret: bool):
+    """Run ``kernel`` over (T, B) f32 ``inputs`` in (T, bt) blocks, one
+    "parallel" grid step per lane tile; returns ``n_out`` (T, B) outputs."""
+    inputs = batch_major(*inputs)
+    t, b = inputs[0].shape
+    bt = _lane_tile(t, b, len(inputs) + n_out)
+    nb = -(-b // bt)
+    pad = nb * bt - b
+    if pad:
+        inputs = [jnp.pad(x, ((0, 0), (0, pad))) for x in inputs]
+    spec = pl.BlockSpec((t, bt), lambda j: (0, j))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=[spec] * len(inputs),
+        out_specs=[spec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((t, nb * bt), jnp.float32)] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(*inputs)
+    return [o[:, :b] for o in outs] if pad else list(outs)
+
+
+def _gae_kernel(r_ref, v_ref, nv_ref, d_ref, adv_ref, *,
                 gamma: float, lam: float):
-    t = pl.program_id(0)
+    t_len = r_ref.shape[0]
 
-    @pl.when(t == 0)
-    def _init():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
+    def step(i, carry):                                     # carry (1, bt)
+        row = pl.ds(t_len - 1 - i, 1)                       # reverse time
+        r, v, nv, d = r_ref[row, :], v_ref[row, :], nv_ref[row, :], \
+            d_ref[row, :]
+        nd = 1.0 - d
+        delta = r + gamma * nv * nd - v
+        adv = delta + gamma * lam * nd * carry
+        adv_ref[row, :] = adv
+        return adv
 
-    r, v, nv, d = r_ref[0], v_ref[0], nv_ref[0], d_ref[0]   # (B,)
-    nd = 1.0 - d
-    delta = r + gamma * nv * nd - v
-    adv = delta + gamma * lam * nd * carry_ref[...]
-    carry_ref[...] = adv
-    adv_ref[0] = adv
+    jax.lax.fori_loop(0, t_len, step,
+                      jnp.zeros((1, r_ref.shape[1]), jnp.float32))
 
 
 def _gae_forward(rewards, values, next_values, dones, *,
                  gamma: float, lam: float, interpret: bool):
-    t, b = rewards.shape
-    rev = lambda ti: (t - 1 - ti, 0)       # reverse time through index map
-    spec = pl.BlockSpec((1, b), rev)
-    return pl.pallas_call(
+    (adv,) = _lane_tiled_call(
         functools.partial(_gae_kernel, gamma=gamma, lam=lam),
-        grid=(t,),
-        in_specs=[spec, spec, spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((t, b), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((b,), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(rewards, values, next_values, dones)
+        [rewards, values, next_values, dones], 1, interpret)
+    return adv
 
 
-def _gae_bwd_kernel(g_ref, d_ref, dr_ref, dnv_ref, carry_ref, *,
+def _gae_bwd_kernel(g_ref, d_ref, dr_ref, dnv_ref, *,
                     gamma: float, lam: float):
-    """Adjoint step, forward in time. carry holds γλ(1-d_{t-1})·ā_{t-1}."""
-    t = pl.program_id(0)
+    """Adjoint scan, forward in time. carry holds γλ(1-d_{t-1})·ā_{t-1}."""
 
-    @pl.when(t == 0)
-    def _init():
-        carry_ref[...] = jnp.zeros_like(carry_ref)
+    def step(t, carry):
+        row = pl.ds(t, 1)
+        g, d = g_ref[row, :], d_ref[row, :]
+        nd = 1.0 - d
+        abar = g + carry
+        dr_ref[row, :] = abar
+        dnv_ref[row, :] = gamma * nd * abar
+        return gamma * lam * nd * abar
 
-    g, d = g_ref[0], d_ref[0]                               # (B,)
-    nd = 1.0 - d
-    abar = g + carry_ref[...]
-    dr_ref[0] = abar
-    dnv_ref[0] = gamma * nd * abar
-    carry_ref[...] = gamma * lam * nd * abar
+    jax.lax.fori_loop(0, g_ref.shape[0], step,
+                      jnp.zeros((1, g_ref.shape[1]), jnp.float32))
 
 
 def _gae_backward(g, dones, *, gamma: float, lam: float, interpret: bool):
-    t, b = g.shape
-    spec = pl.BlockSpec((1, b), lambda ti: (ti, 0))         # forward time
-    return pl.pallas_call(
+    return _lane_tiled_call(
         functools.partial(_gae_bwd_kernel, gamma=gamma, lam=lam),
-        grid=(t,),
-        in_specs=[spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((t, b), jnp.float32),
-                   jax.ShapeDtypeStruct((t, b), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((b,), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(g, dones)
+        [g, dones], 2, interpret)
 
 
 @functools.lru_cache(maxsize=None)

@@ -17,6 +17,10 @@ accumulator dW_h, and the bias accumulator db_h all stay resident in
 VMEM across the whole scan; per-step gate gradients stream out as dgi,
 which XLA then turns into dx/dW_i through the outer matmul's own VJP.
 
+The biases ride as (1, 3H) rows inside this module — a 1-D block
+breaks the TPU's (8, 128) tiling rule once ``vmap`` adds the agent axis —
+while :func:`gru_scan` keeps the public (3H,) shapes.
+
 VMEM at B=256, H=128: h(B·H) + gi(B·3H) + Wh(H·3H) fp32 ≈ 0.7 MB
 forward; backward adds the dWh/dbh accumulators (+0.2 MB).
 """
@@ -29,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.layout import batch_major
 
 
 def _gates(gi, gh, hdim):
@@ -63,6 +67,8 @@ def _gru_kernel(gi_ref, wh_ref, bh_ref, reset_ref, h0_ref, hs_ref, h_ref):
 
 def _gru_forward(gi, wh, bh, h0, resets, interpret: bool):
     t, bsz, h3 = gi.shape
+    gi, wh, bh, h0, resets = batch_major(gi, wh, bh.reshape(1, h3), h0,
+                                         resets)
     hdim = h3 // 3
     return pl.pallas_call(
         _gru_kernel,
@@ -70,14 +76,14 @@ def _gru_forward(gi, wh, bh, h0, resets, interpret: bool):
         in_specs=[
             pl.BlockSpec((1, bsz, h3), lambda ti: (ti, 0, 0)),
             pl.BlockSpec((hdim, h3), lambda ti: (0, 0)),
-            pl.BlockSpec((h3,), lambda ti: (0,)),
+            pl.BlockSpec((1, h3), lambda ti: (0, 0)),
             pl.BlockSpec((1, bsz, 1), lambda ti: (ti, 0, 0)),
             pl.BlockSpec((bsz, hdim), lambda ti: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bsz, hdim), lambda ti: (ti, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, bsz, hdim), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bsz, hdim), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(gi, wh, bh, resets, h0)
@@ -121,7 +127,7 @@ def _gru_bwd_kernel(gi_ref, hprev_ref, reset_ref, wh_ref, bh_ref, g_ref,
     dwh_ref[...] += jax.lax.dot_general(
         hp, dgh, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    dbh_ref[...] += dgh.sum(axis=0)
+    dbh_ref[...] += dgh.sum(axis=0, keepdims=True)
     dh_ref[...] = dhp * (1.0 - m)       # adjoint on h_{t-1}
 
     @pl.when(t == nt - 1)
@@ -134,6 +140,8 @@ def _gru_backward(gi, wh, bh, h0, resets, hs, g, interpret: bool):
     hdim = h3 // 3
     # h_{t-1} for every step: [h0, hs[0], ..., hs[T-2]]
     hprev = jnp.concatenate([h0[None], hs[:-1]], axis=0)
+    gi, hprev, resets, wh, bh, g = batch_major(
+        gi, hprev, resets, wh, bh.reshape(1, h3), g)
     rev3 = lambda ti: (t - 1 - ti, 0, 0)
     const2 = lambda ti: (0, 0)
     return pl.pallas_call(
@@ -144,23 +152,23 @@ def _gru_backward(gi, wh, bh, h0, resets, hs, g, interpret: bool):
             pl.BlockSpec((1, bsz, hdim), rev3),           # hprev
             pl.BlockSpec((1, bsz, 1), rev3),              # resets
             pl.BlockSpec((hdim, h3), const2),             # wh
-            pl.BlockSpec((h3,), lambda ti: (0,)),         # bh
+            pl.BlockSpec((1, h3), const2),                # bh
             pl.BlockSpec((1, bsz, hdim), rev3),           # g (dL/dhs)
         ],
         out_specs=[
             pl.BlockSpec((1, bsz, h3), rev3),             # dgi
             pl.BlockSpec((hdim, h3), const2),             # dwh
-            pl.BlockSpec((h3,), lambda ti: (0,)),         # dbh
+            pl.BlockSpec((1, h3), const2),                # dbh
             pl.BlockSpec((bsz, hdim), const2),            # dh0
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, bsz, h3), jnp.float32),
             jax.ShapeDtypeStruct((hdim, h3), jnp.float32),
-            jax.ShapeDtypeStruct((h3,), jnp.float32),
+            jax.ShapeDtypeStruct((1, h3), jnp.float32),
             jax.ShapeDtypeStruct((bsz, hdim), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bsz, hdim), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(gi, hprev, resets, wh, bh, g)
@@ -184,7 +192,7 @@ def _gru_scan_with_vjp(interpret: bool):
         gi, wh, bh, h0, resets, hs = res
         dgi, dwh, dbh, dh0 = _gru_backward(
             gi, wh, bh, h0, resets, hs, g, interpret)
-        return dgi, dwh, dbh, dh0, jnp.zeros_like(resets)
+        return dgi, dwh, dbh.reshape(bh.shape), dh0, jnp.zeros_like(resets)
 
     scan_fn.defvjp(fwd, bwd)
     return scan_fn

@@ -18,9 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_chunk_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, st_ref, cd_ref, *,
@@ -90,7 +88,7 @@ def ssd_intra_chunk(xw, la, b, c, *, chunk: int, interpret: bool = True):
             jax.ShapeDtypeStruct((bsz, nc, h, p, n), jnp.float32),
             jax.ShapeDtypeStruct((bsz, nc, h), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(xw, la, b, c)

@@ -24,6 +24,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from repro.obs import metrics, sinks
 from repro.obs.trace import (NULL_TRACER, NullTracer, Tracer, annotate,
                              profile)
@@ -33,11 +35,7 @@ __all__ = ["Telemetry", "DISABLED", "maybe", "Tracer", "NullTracer",
 
 
 def _default_process_id() -> int:
-    try:
-        import jax
-        return jax.process_index()
-    except Exception:             # pragma: no cover - jax always present
-        return 0
+    return jax.process_index()
 
 
 class Telemetry:

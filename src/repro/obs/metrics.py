@@ -246,6 +246,8 @@ SCALING_ROW_SCHEMA = {
         "gs_speedup": (_NUM, True, True),
         # only present once the shards=1 baseline has run (P=1 cells)
         "speedup_vs_unfused": (_NUM, False, False),
+        # jax.devices()[0].platform of the process that measured the row
+        "platform": (str, False, False),
     },
     "phases": ("round_s", "round_s_async", "collect_s",
                "collect_s_sharded_gs"),

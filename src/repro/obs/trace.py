@@ -34,25 +34,14 @@ import contextlib
 import time
 from typing import Dict, List, Optional
 
-
-def _jax_profiler():
-    try:
-        import jax
-        return jax.profiler
-    except Exception:             # pragma: no cover - jax always present
-        return None
+import jax
 
 
 def annotate(name: str):
-    """Trace-time scope naming for jitted code: ``jax.named_scope``
-    pass-through (a no-op context manager on jax builds without it).
+    """Trace-time scope naming for jitted code: ``jax.named_scope``.
     Adds HLO metadata only — never a primitive, so the collective
     audits of ``repro.distributed.runtime`` see identical programs."""
-    try:
-        import jax
-        return jax.named_scope(name)
-    except (ImportError, AttributeError):   # pragma: no cover
-        return contextlib.nullcontext()
+    return jax.named_scope(name)
 
 
 @contextlib.contextmanager
@@ -63,15 +52,11 @@ def profile(directory: Optional[str]):
     if not directory:
         yield
         return
-    prof = _jax_profiler()
-    if prof is None:              # pragma: no cover
-        yield
-        return
-    prof.start_trace(directory)
+    jax.profiler.start_trace(directory)
     try:
         yield
     finally:
-        prof.stop_trace()
+        jax.profiler.stop_trace()
 
 
 class Span:
@@ -86,7 +71,6 @@ class Span:
 
     def fence(self, value):
         if self._tracer.fenced:
-            import jax
             jax.block_until_ready(value)
         return value
 
@@ -106,12 +90,8 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str):
-        prof = _jax_profiler()
-        ann = (prof.TraceAnnotation(name)
-               if prof is not None and hasattr(prof, "TraceAnnotation")
-               else contextlib.nullcontext())
         depth, self._depth = self._depth, self._depth + 1
-        with ann:
+        with jax.profiler.TraceAnnotation(name):
             t0 = self._clock()
             sp = Span(self, name, depth, t0)
             try:

@@ -44,11 +44,20 @@ def test_type_bytes_dtypes():
 def test_roofline_terms():
     from benchmarks import roofline
     terms = roofline.terms(flops=1e15, bytes_accessed=1e12,
-                           collective_bytes=1e9, n_devices=256)
+                           collective_bytes=1e9, n_devices=256,
+                           device_kind="TPU v5 lite")
+    peaks = roofline.peaks("TPU v5 lite")
     assert terms["compute_s"] == pytest.approx(
-        1e15 / (256 * roofline.PEAK_FLOPS), rel=1e-6)
+        1e15 / (256 * peaks["flops"]), rel=1e-6)
     assert terms["memory_s"] == pytest.approx(
-        1e12 / (256 * roofline.HBM_BW), rel=1e-6)
+        1e12 / (256 * peaks["hbm_bytes_per_s"]), rel=1e-6)
     assert terms["collective_s"] == pytest.approx(
-        1e9 / (256 * roofline.ICI_BW), rel=1e-6)
+        1e9 / (256 * peaks["ici_bytes_per_s"]), rel=1e-6)
     assert terms["bottleneck"] in ("compute", "memory", "collective")
+
+
+def test_roofline_unknown_device_kind_raises():
+    from benchmarks import roofline
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline.terms(flops=1.0, bytes_accessed=1.0, collective_bytes=0.0,
+                       n_devices=1, device_kind="cpu")

@@ -274,6 +274,36 @@ def test_gae_kernel_grad_matches_ref(shape):
     assert tree_maxdiff(gk, gr) < 1e-5
 
 
+def test_gae_kernel_lane_tiled_matches_ref(monkeypatch):
+    """A batch too wide for the VMEM budget is split into 128-lane tiles
+    over a parallel grid axis (zero-padded to whole tiles): forward and
+    grads still match the oracle."""
+    from repro.kernels.gae import kernel as gae_kernel
+    monkeypatch.setattr(gae_kernel, "_VMEM_BUDGET", 2 * 5 * 16 * 4 * 128)
+    shape = (300, 16)                     # B=300 -> 3 tiles of 128
+    assert gae_kernel._lane_tile(16, 300, 5) == 128
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    rewards = jax.random.normal(ks[0], shape)
+    values = jax.random.normal(ks[1], shape)
+    dones = jax.random.bernoulli(ks[2], 0.1, shape).astype(jnp.float32)
+    last_value = jax.random.normal(ks[3], shape[:-1])
+    g = jax.random.normal(ks[4], shape)
+
+    def loss(gae_fn):
+        def f(r, v, lv):
+            adv, ret = gae_fn(r, v, lv)
+            return (adv * g).sum() + (ret ** 2).sum()
+        return jax.value_and_grad(f, argnums=(0, 1, 2))
+
+    vk, gk = loss(lambda r, v, lv: gae_ops.gae(r, v, dones, lv,
+                                               interpret=True))(
+        rewards, values, last_value)
+    vr, gr = loss(lambda r, v, lv: gae_ref.gae(r, v, dones, lv))(
+        rewards, values, last_value)
+    np.testing.assert_allclose(float(vk), float(vr), rtol=1e-6)
+    assert tree_maxdiff(gk, gr) < 1e-5
+
+
 def test_gae_oracle_traces_and_round_trips_bf16():
     """The oracle used to desync its scan carry dtype under bf16 inputs
     (the (1 - d) masking promotes to f32) and crash at trace time; it

@@ -181,16 +181,7 @@ def test_outer_step_cross_pod_mean_under_shard_map():
     from functools import partial
     from jax.sharding import PartitionSpec as P
 
-    import inspect
-
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:            # older jax: pre-promotion location
-        from jax.experimental.shard_map import shard_map
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    # independently of the promotion; key on the signature, not the location
-    _kw = ("check_vma" if "check_vma"
-           in inspect.signature(shard_map).parameters else "check_rep")
-    shard_map = partial(shard_map, **{_kw: False})
+    shard_map = partial(jax.shard_map, check_vma=False)
 
     @partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=P())
     def f(lp, anchor_vel_w):
