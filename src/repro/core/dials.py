@@ -71,6 +71,7 @@ from repro.marl import policy as policy_mod
 from repro.marl import ppo as ppo_mod
 from repro.marl import runner as runner_mod
 from repro.obs import metrics as obs_metrics
+from repro.obs.trace import SYNC
 
 
 @dataclasses.dataclass(frozen=True)
@@ -301,10 +302,11 @@ class DIALSTrainer:
     def _ckpt_extra(self, collector, reports) -> dict:
         """What a checkpoint must carry beyond the state tree for an
         exact resume: the in-flight async collect's round tag and the
-        per-agent data-report rounds (staleness bookkeeping)."""
+        per-agent data-report rounds (staleness bookkeeping), already
+        read to the host."""
         return {"async_round": (collector.pending_round
                                 if collector is not None else None),
-                "reports": jax.device_get(reports).tolist()}
+                "reports": reports.tolist()}
 
     def _restored_reports(self, state):
         """The resumed ``reports`` vector: the checkpointed one when
@@ -424,6 +426,7 @@ class DIALSTrainer:
                 "single-device loop path always uses the replicated GS")
         n = self.info.n_agents
         tel = obs.maybe(cfg.telemetry_dir, fence=cfg.telemetry_fence)
+        tr = tel.tracer
         kernels = obs_metrics.kernel_summary(self.policy_cfg, self.aip_cfg,
                                              self.ppo_cfg)
         collector = (self._make_collector_executor(tel)
@@ -448,107 +451,130 @@ class DIALSTrainer:
             for rnd in range(state["round"], cfg.outer_rounds):
                 if chaos is not None:
                     chaos.round_start(rnd)
-                tel.reset_spans()
+                tr.reset()
                 t_round = time.perf_counter()
-                key = jax.random.fold_in(state["key"], rnd)
-                kc, kt, ke = jax.random.split(key, 3)
+                with tr.span("dials.round"):
+                    key = jax.random.fold_in(state["key"], rnd)
+                    kc, kt, ke = jax.random.split(key, 3)
 
-                # (1) Algorithm 2: datasets from the GS. Async: consume
-                # the double buffer (freshness-gated; round 0 primes with
-                # a blocking collect) and launch the NEXT round's collect
-                # under THIS round's entry policy — it overlaps the F
-                # inner steps below and is consumed one round later.
-                with tel.span("collect") as sp:
-                    if collector is not None:
-                        tagged, forced_sync = collector.obtain(
-                            rnd, state["ials"]["params"], kc,
-                            max_staleness=cfg.max_aip_staleness)
-                        # pipeline the next round's collect — unless the
-                        # bound forbids any lag (a tag-rnd dataset could
-                        # never be consumed at rnd+1, so don't collect it)
-                        if (rnd + 1 < cfg.outer_rounds and collector.idle()
-                                and cfg.max_aip_staleness > 0):
-                            collector.submit(
-                                state["ials"]["params"],
-                                self._collect_key(state["key"], rnd + 1),
-                                rnd)
-                        data, data_round = tagged.data, tagged.round
-                    else:
-                        data = self._ring.collect(state["ials"]["params"],
-                                                  kc)
-                        data_round, forced_sync = rnd, False
-                    sp.fence(data)
+                    # (1) Algorithm 2: datasets from the GS. Async: consume
+                    # the double buffer (freshness-gated; round 0 primes with
+                    # a blocking collect) and launch the NEXT round's collect
+                    # under THIS round's entry policy — it overlaps the F
+                    # inner steps below and is consumed one round later.
+                    with tr.span("dials.collect"):
+                        if collector is not None:
+                            with tr.span(SYNC + "obtain"):
+                                tagged, forced_sync = collector.obtain(
+                                    rnd, state["ials"]["params"], kc,
+                                    max_staleness=cfg.max_aip_staleness)
+                            # pipeline the next round's collect — unless the
+                            # bound forbids any lag (a tag-rnd dataset could
+                            # never be consumed at rnd+1, so don't collect it)
+                            if (rnd + 1 < cfg.outer_rounds and collector.idle()
+                                    and cfg.max_aip_staleness > 0):
+                                collector.submit(
+                                    state["ials"]["params"],
+                                    self._collect_key(state["key"], rnd + 1),
+                                    rnd)
+                            data, data_round = tagged.data, tagged.round
+                        else:
+                            data = self._ring.collect(state["ials"]["params"],
+                                                      kc)
+                            data_round, forced_sync = rnd, False
+                        tr.fence(data)
 
-                # (2) fused AIP round: holdout split + held-out CE + AIP
-                # training + bounded-staleness gate, one jitted program
-                # reading the ring slot in place (training is skipped for
-                # untrained-DIALS — a static branch of the program)
-                with tel.span("aip_train") as sp:
-                    mask = (jnp.asarray(straggler_mask(rnd), jnp.float32)
-                            if straggler_mask is not None
-                            else jnp.ones((n,), jnp.float32))
-                    (state["aips"], reports, ce_before, ce_after,
-                     forced) = self.aip_round(
-                        state["aips"], data, jax.random.split(kt, n),
-                        mask, reports, rnd, data_round)
-                    stale_forced = int(forced.sum())
-                    sp.fence((ce_before, ce_after))
+                    # (2) fused AIP round: holdout split + held-out CE + AIP
+                    # training + bounded-staleness gate, one jitted program
+                    # reading the ring slot in place (training is skipped for
+                    # untrained-DIALS — a static branch of the program)
+                    with tr.span("dials.aip_train"):
+                        mask = (jnp.asarray(straggler_mask(rnd), jnp.float32)
+                                if straggler_mask is not None
+                                else jnp.ones((n,), jnp.float32))
+                        (state["aips"], reports, ce_before, ce_after,
+                         forced) = self.aip_round(
+                            state["aips"], data, jax.random.split(kt, n),
+                            mask, reports, rnd, data_round)
+                        stale_forced = tr.pull("stale_forced", forced.sum(),
+                                               int)
+                        tr.fence((ce_before, ce_after))
 
-                # (3) F inner IALS+PPO steps, AIPs frozen
-                with tel.span("inner_steps") as sp:
-                    metrics = None
-                    for _ in range(cfg.aip_refresh):
-                        state["ials"], metrics = self.ials_train(
-                            state["ials"], state["aips"])
-                    sp.fence(state["ials"])
+                    # (3) F inner IALS+PPO steps, AIPs frozen
+                    with tr.span("dials.inner_steps"):
+                        metrics = None
+                        for _ in range(cfg.aip_refresh):
+                            state["ials"], metrics = self.ials_train(
+                                state["ials"], state["aips"])
+                        tr.fence(state["ials"])
 
-                with tel.span("gs_eval") as sp:
-                    ret = sp.fence(self.gs_eval(
-                        state["ials"]["params"], ke,
-                        episodes=cfg.eval_episodes))
-                phases = tel.phase_seconds()
-                stats = obs_metrics.staleness_stats(reports, rnd)
-                # collect throughput (sync path only — the async span
-                # measures obtain wait, not simulator time)
-                collect_span = phases.get("collect")
-                env_steps = collect_stream_count(cfg) * cfg.collect_steps
-                env_rate = (env_steps / collect_span
-                            if collector is None and collect_span
-                            else None)
-                rec = obs_metrics.round_record(
-                    round=rnd,
-                    gs_return=ret,
-                    ials_reward=(None if metrics is None
-                                 else metrics["reward"]),
-                    aip_ce_before=ce_before.mean(),
-                    aip_ce_after=ce_after.mean(),
-                    data_round=data_round,
-                    forced_sync=forced_sync,
-                    stale_forced=stale_forced,
-                    staleness_min=stats["staleness_min"],
-                    staleness_mean=stats["staleness_mean"],
-                    staleness_max=stats["staleness_max"],
-                    n_shards=1,
-                    reassigned=0,
-                    dead_hosts=[],
-                    kernels=kernels,
-                    collect_s=collect_span,
-                    env_steps_per_s=env_rate,
-                    aip_s=phases.get("aip_train"),
-                    inner_s=phases.get("inner_steps"),
-                    eval_s=phases.get("gs_eval"),
-                    mirror_s=None,
-                    round_s=time.perf_counter() - t_round,
-                    wall_s=time.time() - t_start)
+                    with tr.span("dials.gs_eval"):
+                        ret = tr.fence(self.gs_eval(
+                            state["ials"]["params"], ke,
+                            episodes=cfg.eval_episodes))
+
+                    # the round's reads, one dials.sync span each, in the
+                    # order the record lists them
+                    with tr.span("dials.record"):
+                        stats = obs_metrics.staleness_stats(reports, rnd)
+                        ce_before = ce_before.mean()
+                        ce_after = ce_after.mean()
+                        gs_return = tr.pull("gs_return", ret)
+                        ials_reward = (
+                            None if metrics is None
+                            else tr.pull("ials_reward", metrics["reward"]))
+                        ce_before = tr.pull("aip_ce_before", ce_before)
+                        ce_after = tr.pull("aip_ce_after", ce_after)
+                        lag_min = tr.pull("staleness_min",
+                                          stats["staleness_min"], int)
+                        lag_mean = tr.pull("staleness_mean",
+                                           stats["staleness_mean"])
+                        lag_max = tr.pull("staleness_max",
+                                          stats["staleness_max"], int)
+                        round_s = time.perf_counter() - t_round
+                        phases = tr.phase_seconds()
+                        # collect throughput (sync path only — the async span
+                        # measures obtain wait, not simulator time)
+                        collect_span = phases.get("dials.collect")
+                        env_steps = (collect_stream_count(cfg)
+                                     * cfg.collect_steps)
+                        env_rate = (env_steps / collect_span
+                                    if collector is None and collect_span
+                                    else None)
+                        rec = obs_metrics.round_record(
+                            round=rnd,
+                            gs_return=gs_return,
+                            ials_reward=ials_reward,
+                            aip_ce_before=ce_before,
+                            aip_ce_after=ce_after,
+                            data_round=data_round,
+                            forced_sync=forced_sync,
+                            stale_forced=stale_forced,
+                            staleness_min=lag_min,
+                            staleness_mean=lag_mean,
+                            staleness_max=lag_max,
+                            n_shards=1,
+                            reassigned=0,
+                            dead_hosts=[],
+                            kernels=kernels,
+                            collect_s=collect_span,
+                            env_steps_per_s=env_rate,
+                            aip_s=phases.get("dials.aip_train"),
+                            inner_s=phases.get("dials.inner_steps"),
+                            eval_s=phases.get("dials.gs_eval"),
+                            mirror_s=None,
+                            sync_s=tr.sync_seconds(),
+                            round_s=round_s,
+                            wall_s=time.time() - t_start)
                 tel.emit_round(rec)
                 history.append(rec)
                 if log:
                     log(rec)
                 state["round"] = rnd + 1
                 if self.manager is not None:
-                    self.manager.save(rnd + 1, state,
-                                      extra=self._ckpt_extra(collector,
-                                                             reports))
+                    self.manager.save(rnd + 1, state, extra=self._ckpt_extra(
+                        collector, tr.pull("reports", reports,
+                                           jax.device_get)))
         finally:
             if collector is not None:
                 collector.close()
@@ -639,6 +665,7 @@ class DIALSTrainer:
             {"aips": state["aips"], "ials": state["ials"],
              "reports": self._restored_reports(state)})
         tel = obs.maybe(cfg.telemetry_dir, fence=cfg.telemetry_fence)
+        tr = tel.tracer
         kernels = obs_metrics.kernel_summary(self.policy_cfg, self.aip_cfg,
                                              self.ppo_cfg)
         # the distributed per-slice manager works on any process count —
@@ -667,77 +694,80 @@ class DIALSTrainer:
                     # the round boundary: the one point where killing a
                     # host cannot strand survivors inside a collective
                     chaos.round_start(rnd)
+                tr.reset()
                 t_round = time.perf_counter()
-                dead_hosts, reassigned = (), 0
-                if elastic:
-                    dead_hosts = tuple(heartbeats(rnd))
-                    if dead_hosts:
-                        runner, carry, collector, reassigned = \
-                            self._reassign(runner, carry, mirror,
-                                           collector, dead_hosts, tel)
-                mask = (jnp.asarray(straggler_mask(rnd), jnp.float32)
-                        if straggler_mask is not None and not cfg.untrained
-                        else jnp.ones((n,), jnp.float32))
-                if collector is None:
-                    carry, rec = runner.round(carry, base_key, rnd, mask)
-                    forced_sync, collect_s = False, None
-                else:
-                    tagged, forced_sync = collector.obtain(
-                        rnd, carry["ials"]["params"],
-                        self._collect_key(base_key, rnd),
-                        max_staleness=cfg.max_aip_staleness)
-                    # a tag-rnd dataset can only be consumed if the bound
-                    # tolerates one round of lag
-                    if (rnd + 1 < cfg.outer_rounds and collector.idle()
-                            and cfg.max_aip_staleness > 0):
-                        collector.submit(
-                            carry["ials"]["params"],
-                            self._collect_key(base_key, rnd + 1), rnd)
-                    # agent-shard the dataset onto the mesh (it arrives on
-                    # the spare device when one exists); an async transfer.
-                    # Identity for the region-decomposed collect — its
-                    # output is born mesh-sharded.
-                    data = runner.place_dataset(tagged.data)
-                    carry, rec = runner.train_round(
-                        carry, data, base_key, rnd, tagged.round, mask)
-                    collect_s = collector.last_obtain_wait_s
-                # the ONE deliberate host sync of the round: fetching the
-                # on-mesh record (telemetry scalars included — they were
-                # computed inside the round program, not by extra fetches)
-                raw = {k: float(v) for k, v in rec.items()}
-                mirror_s = None
-                if elastic:
-                    # the availability tax: refresh the host mirror the
-                    # NEXT round's reassignment would restore from (an
-                    # all-gather on a multi-process mesh)
-                    t_mirror = time.perf_counter()
-                    mirror = runner.unshard_carry(carry)
-                    if tel.tracer.fenced:
-                        jax.block_until_ready(mirror)
-                    mirror_s = time.perf_counter() - t_mirror
-                rec = obs_metrics.round_record(
-                    round=rnd,
-                    gs_return=raw["gs_return"],
-                    ials_reward=(None if cfg.aip_refresh == 0
-                                 else raw["ials_reward"]),
-                    aip_ce_before=raw["aip_ce_before"],
-                    aip_ce_after=raw["aip_ce_after"],
-                    data_round=raw["data_round"],
-                    forced_sync=forced_sync,
-                    stale_forced=raw["stale_forced"],
-                    staleness_min=raw["staleness_min"],
-                    staleness_mean=raw["staleness_mean"],
-                    staleness_max=raw["staleness_max"],
-                    n_shards=runner.n_shards,
-                    reassigned=reassigned,
-                    dead_hosts=list(dead_hosts),
-                    kernels=kernels,
-                    collect_s=collect_s,
-                    env_steps_per_s=None,
-                    aip_s=None, inner_s=None, eval_s=None,
-                    mirror_s=mirror_s,
-                    round_s=time.perf_counter() - t_round,
-                    wall_s=time.time() - t_start)
+                with tr.span("dials.round"):
+                    dead_hosts, reassigned = (), 0
+                    if elastic:
+                        dead_hosts = tuple(heartbeats(rnd))
+                        if dead_hosts:
+                            runner, carry, collector, reassigned = \
+                                self._reassign(runner, carry, mirror,
+                                               collector, dead_hosts, tel)
+                    mask = (jnp.asarray(straggler_mask(rnd), jnp.float32)
+                            if straggler_mask is not None and not cfg.untrained
+                            else jnp.ones((n,), jnp.float32))
+                    if collector is None:
+                        carry, rec = runner.round(carry, base_key, rnd, mask)
+                        forced_sync, collect_s = False, None
+                    else:
+                        with tr.span(SYNC + "obtain"):
+                            tagged, forced_sync = collector.obtain(
+                                rnd, carry["ials"]["params"],
+                                self._collect_key(base_key, rnd),
+                                max_staleness=cfg.max_aip_staleness)
+                        # a tag-rnd dataset can only be consumed if the bound
+                        # tolerates one round of lag
+                        if (rnd + 1 < cfg.outer_rounds and collector.idle()
+                                and cfg.max_aip_staleness > 0):
+                            collector.submit(
+                                carry["ials"]["params"],
+                                self._collect_key(base_key, rnd + 1), rnd)
+                        # agent-shard the dataset onto the mesh (it arrives on
+                        # the spare device when one exists); an async transfer.
+                        # Identity for the region-decomposed collect — its
+                        # output is born mesh-sharded.
+                        data = runner.place_dataset(tagged.data)
+                        carry, rec = runner.train_round(
+                            carry, data, base_key, rnd, tagged.round, mask)
+                        collect_s = collector.last_obtain_wait_s
+                    # the round's host syncs: one read per key of the on-mesh
+                    # record (telemetry scalars included — they were computed
+                    # inside the round program, not by extra fetches)
+                    raw = {k: tr.pull(k, v) for k, v in rec.items()}
+                    mirror_s = None
+                    if elastic:
+                        # the availability tax: refresh the host mirror the
+                        # NEXT round's reassignment would restore from (an
+                        # all-gather on a multi-process mesh)
+                        with tr.span("dials.mirror"):
+                            t_mirror = time.perf_counter()
+                            mirror = tr.fence(runner.unshard_carry(carry))
+                            mirror_s = time.perf_counter() - t_mirror
+                    rec = obs_metrics.round_record(
+                        round=rnd,
+                        gs_return=raw["gs_return"],
+                        ials_reward=(None if cfg.aip_refresh == 0
+                                     else raw["ials_reward"]),
+                        aip_ce_before=raw["aip_ce_before"],
+                        aip_ce_after=raw["aip_ce_after"],
+                        data_round=raw["data_round"],
+                        forced_sync=forced_sync,
+                        stale_forced=raw["stale_forced"],
+                        staleness_min=raw["staleness_min"],
+                        staleness_mean=raw["staleness_mean"],
+                        staleness_max=raw["staleness_max"],
+                        n_shards=runner.n_shards,
+                        reassigned=reassigned,
+                        dead_hosts=list(dead_hosts),
+                        kernels=kernels,
+                        collect_s=collect_s,
+                        env_steps_per_s=None,
+                        aip_s=None, inner_s=None, eval_s=None,
+                        mirror_s=mirror_s,
+                        sync_s=tr.sync_seconds(),
+                        round_s=time.perf_counter() - t_round,
+                        wall_s=time.time() - t_start)
                 tel.emit_round(rec)
                 history.append(rec)
                 if log:
@@ -751,7 +781,8 @@ class DIALSTrainer:
                         "round": rnd + 1, "key": base_key},
                         extra=self._ckpt_extra(
                             collector,
-                            runtime_lib.fetch_tree(carry["reports"])))
+                            tr.pull("reports", carry["reports"],
+                                    runtime_lib.fetch_tree)))
         finally:
             tel.emit("run_end", rounds=len(history))
             tel.close()

@@ -38,12 +38,13 @@ body enforces ``max_aip_staleness`` through
 to the bound, then force-refreshed), with the per-agent report rounds
 carried on-mesh.
 
-Host syncs per round: 1 (reading the metrics record). Telemetry holds
-that line: the observability scalars (staleness distribution, CE, forced
-counts — ``repro.obs.metrics``) accumulate on-mesh inside this program
-and ride the same record fetch; host-side spans and sinks live entirely
-in the driver, so enabling telemetry does not change the traced round
-program at all.
+Host syncs per round: the metrics record, one read per scalar (the
+driver's ``dials.sync.<key>`` spans). Telemetry holds that line: the
+observability scalars (staleness distribution, CE, forced counts —
+``repro.obs.metrics``) accumulate on-mesh inside this program and ride
+the same record fetch; host-side spans and sinks live entirely in the
+driver, so enabling telemetry does not change the traced round program
+at all.
 """
 from __future__ import annotations
 

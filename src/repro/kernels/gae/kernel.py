@@ -17,6 +17,9 @@ transposed recurrence — a FORWARD-time scan of the advantage cotangent
 elementwise: dr = ā, dv = -ā, dnv = γ(1-d)·ā. :func:`gae_reverse_scan`
 carries a ``jax.custom_vjp`` running that adjoint as a second Pallas
 kernel (no residuals beyond the dones mask).
+
+The two kernels are named ``gae_fwd`` and ``gae_bwd``; a profile shows
+the names in their operations.
 """
 from __future__ import annotations
 
@@ -44,7 +47,8 @@ def _lane_tile(t: int, b: int, n_arrays: int) -> int:
     return max(128, _VMEM_BUDGET // per_lane // 128 * 128)
 
 
-def _lane_tiled_call(kernel, inputs, n_out: int, interpret: bool):
+def _lane_tiled_call(kernel, inputs, n_out: int, interpret: bool,
+                     name: str):
     """Run ``kernel`` over (T, B) f32 ``inputs`` in (T, bt) blocks, one
     "parallel" grid step per lane tile; returns ``n_out`` (T, B) outputs."""
     inputs = batch_major(*inputs)
@@ -64,6 +68,7 @@ def _lane_tiled_call(kernel, inputs, n_out: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name=name,
     )(*inputs)
     return [o[:, :b] for o in outs] if pad else list(outs)
 
@@ -90,7 +95,7 @@ def _gae_forward(rewards, values, next_values, dones, *,
                  gamma: float, lam: float, interpret: bool):
     (adv,) = _lane_tiled_call(
         functools.partial(_gae_kernel, gamma=gamma, lam=lam),
-        [rewards, values, next_values, dones], 1, interpret)
+        [rewards, values, next_values, dones], 1, interpret, "gae_fwd")
     return adv
 
 
@@ -114,7 +119,7 @@ def _gae_bwd_kernel(g_ref, d_ref, dr_ref, dnv_ref, *,
 def _gae_backward(g, dones, *, gamma: float, lam: float, interpret: bool):
     return _lane_tiled_call(
         functools.partial(_gae_bwd_kernel, gamma=gamma, lam=lam),
-        [g, dones], 2, interpret)
+        [g, dones], 2, interpret, "gae_bwd")
 
 
 @functools.lru_cache(maxsize=None)

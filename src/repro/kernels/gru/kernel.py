@@ -23,6 +23,9 @@ while :func:`gru_scan` keeps the public (3H,) shapes.
 
 VMEM at B=256, H=128: h(B·H) + gi(B·3H) + Wh(H·3H) fp32 ≈ 0.7 MB
 forward; backward adds the dWh/dbh accumulators (+0.2 MB).
+
+The two kernels are named ``gru_fwd`` and ``gru_bwd``; a profile shows
+the names in their operations.
 """
 from __future__ import annotations
 
@@ -86,6 +89,7 @@ def _gru_forward(gi, wh, bh, h0, resets, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="gru_fwd",
     )(gi, wh, bh, resets, h0)
 
 
@@ -171,6 +175,7 @@ def _gru_backward(gi, wh, bh, h0, resets, hs, g, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="gru_bwd",
     )(gi, hprev, resets, wh, bh, g)
 
 

@@ -1,23 +1,23 @@
 """Runtime observability for the DIALS runtime.
 
 ``Telemetry`` is the facade the drivers and the multi-host stack talk
-to: it owns a span :class:`~repro.obs.trace.Tracer`, a per-process
-JSONL sink (``telemetry-p{PID}.jsonl`` in a shared directory — the
-``fault.HostMonitor`` heartbeat-dir pattern), and any extra sinks
-(terminal summary, CSV). Every emitted event gets an envelope —
-``event`` kind, ``proc``, per-process monotone ``seq``, unix ``t`` —
-so rank 0 can merge all processes' files into one globally ordered
-``telemetry.jsonl`` (:func:`repro.obs.sinks.merge_dir`).
+to: it owns a span :class:`~repro.obs.trace.Tracer` (``tracer``) and a
+per-process JSONL sink (``telemetry-p{PID}.jsonl`` in a shared
+directory — the ``fault.HostMonitor`` heartbeat-dir pattern). Every
+emitted event gets an envelope — ``event`` kind, ``proc``, per-process
+monotone ``seq``, unix ``t`` — so rank 0 can merge all processes' files
+into one globally ordered ``telemetry.jsonl``
+(:func:`repro.obs.sinks.merge_dir`).
 
 The disabled instance is :data:`DISABLED` (also what
 :func:`maybe` returns for a ``None`` directory): ``emit`` is a no-op,
-``span`` is the shared null span, and **no files are created** — the
-drivers keep their telemetry calls unconditionally and pay nothing
-when it is off. Crucially, telemetry is host-side only: enabling it
-never changes the traced round program, so the sharded driver's
-once-per-round host-sync contract is untouched (the on-mesh scalars
-it reports — staleness stats, CE — ride the round record the driver
-already fetches).
+its ``tracer`` is :data:`~repro.obs.trace.NULL_TRACER` (spans that are
+bare profiler annotations), and **no files are created** — the drivers
+keep their telemetry calls unconditionally and pay nothing when it is
+off. Crucially, telemetry is host-side only: enabling it never changes
+the traced round program, so the sharded driver's round sync contract
+is untouched (the on-mesh scalars it reports — staleness stats, CE —
+ride the round record the driver already fetches).
 """
 from __future__ import annotations
 
@@ -44,30 +44,22 @@ class Telemetry:
     enabled = True
 
     def __init__(self, directory: str, *, process_id: int,
-                 tracer: Tracer, extra_sinks=()):
+                 tracer: Tracer):
         self.directory = directory
         self.process_id = process_id
         self.tracer = tracer
         self._seq = 0
         self._sinks: List = [sinks.JsonlSink(
             sinks.proc_path(directory, process_id))]
-        self._sinks.extend(extra_sinks)
 
     @classmethod
     def create(cls, directory: str, *, process_id: Optional[int] = None,
-               terminal: bool = False, csv: Optional[str] = None,
                fence: bool = False) -> "Telemetry":
         import os
         os.makedirs(directory, exist_ok=True)
-        extra = []
-        if terminal:
-            extra.append(sinks.TerminalSink())
-        if csv:
-            extra.append(sinks.CsvSink(csv))
         pid = process_id if process_id is not None \
             else _default_process_id()
-        return cls(directory, process_id=pid, tracer=Tracer(fenced=fence),
-                   extra_sinks=extra)
+        return cls(directory, process_id=pid, tracer=Tracer(fenced=fence))
 
     def emit(self, event: str, **fields) -> Dict:
         """Wrap ``fields`` in the envelope and write to every sink."""
@@ -82,15 +74,6 @@ class Telemetry:
         """Emit a (already :func:`metrics.round_record`-typed) round
         record as a ``"round"`` event."""
         return self.emit("round", **rec)
-
-    def span(self, name: str):
-        return self.tracer.span(name)
-
-    def phase_seconds(self) -> Dict[str, float]:
-        return self.tracer.phase_seconds()
-
-    def reset_spans(self) -> None:
-        self.tracer.reset()
 
     def merge(self) -> str:
         """Merge every process's event file in this directory (call on
@@ -115,15 +98,6 @@ class _NullTelemetry:
 
     def emit_round(self, rec: Dict) -> None:
         return None
-
-    def span(self, name: str):
-        return NULL_TRACER.span(name)
-
-    def phase_seconds(self) -> Dict[str, float]:
-        return {}
-
-    def reset_spans(self) -> None:
-        pass
 
     def merge(self) -> None:
         return None

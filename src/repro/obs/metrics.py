@@ -61,7 +61,13 @@ field                   type     nullable  meaning
 ``mirror_s``            float    yes       host-mirror ``fetch_tree`` seconds —
                                            the elasticity availability tax
                                            (null when elasticity is off)
-``round_s``             float    no        wall seconds for this round
+``sync_s``              float    yes       host seconds inside this round's
+                                           ``dials.sync.*`` spans, the
+                                           blocking device-to-host reads
+                                           (null when telemetry is off)
+``round_s``             float    no        wall seconds for this round, up
+                                           to and including the reads of
+                                           its record
 ``wall_s``              float    no        cumulative wall seconds since run
                                            start (monotone per process)
 ======================  =======  ========  =====================================
@@ -69,7 +75,9 @@ field                   type     nullable  meaning
 Null phase columns are *explicit*: the sharded driver runs the whole
 round as one fused jitted program, so per-phase host timings do not
 exist there — the record says so with ``null`` rather than omitting the
-key. Unfenced spans measure dispatch-enqueue time (JAX is async);
+key. The loop path's phase columns read the driver's ``dials.*``
+spans (``repro.obs.trace``). Unfenced spans measure dispatch-enqueue
+time (JAX is async);
 ``DIALSConfig.telemetry_fence`` buys honest device timings at the cost
 of extra host syncs and is therefore off by default.
 
@@ -106,13 +114,12 @@ ROUND_FIELDS: Tuple[Tuple[str, type, bool], ...] = (
     ("inner_s", float, True),
     ("eval_s", float, True),
     ("mirror_s", float, True),
+    ("sync_s", float, True),
     ("round_s", float, False),
     ("wall_s", float, False),
 )
 
 ROUND_KEYS: Tuple[str, ...] = tuple(f[0] for f in ROUND_FIELDS)
-ROUND_PHASES: Tuple[str, ...] = ("collect_s", "aip_s", "inner_s",
-                                 "eval_s", "mirror_s")
 
 ENVELOPE_FIELDS: Tuple[str, ...] = ("event", "proc", "seq", "t")
 
@@ -132,8 +139,9 @@ def _coerce(name: str, typ: type, value):
 def round_record(**fields) -> Dict:
     """Build a validated round record: the key set must be exactly
     :data:`ROUND_KEYS`, nulls only on nullable fields, values coerced to
-    host scalars (device scalars accepted — ``int``/``float`` pull them
-    to host, which is the driver's one deliberate sync point)."""
+    host scalars. The drivers pass host scalars, read through
+    ``Tracer.pull``; a device scalar is accepted and read here, outside
+    any ``dials.sync.*`` span."""
     extra = set(fields) - set(ROUND_KEYS)
     if extra:
         raise TypeError(f"unknown round-record fields: {sorted(extra)}")

@@ -8,8 +8,7 @@ merges all per-process files into one ``telemetry.jsonl`` ordered by
 ``(t, proc, seq)``. No cross-process coordination is needed to write —
 only the merge reads other processes' files.
 
-Also here: a terminal sink (compact one-line summaries for interactive
-runs) and a CSV sink (round events only, columns in
+Also here: a CSV sink (round events only, columns in
 ``metrics.ROUND_FIELDS`` order, for spreadsheet-style analysis).
 """
 from __future__ import annotations
@@ -79,43 +78,6 @@ class JsonlSink:
 
     def close(self) -> None:
         self._f.close()
-
-
-class TerminalSink:
-    """One compact line per event on stdout — the interactive view of
-    the same stream the JSONL sink persists."""
-
-    def __init__(self, prefix: str = "telemetry"):
-        self.prefix = prefix
-
-    def write(self, event: Dict) -> None:
-        kind = event.get("event", "?")
-        if kind == "round":
-            phases = " ".join(
-                f"{p}={event[p]:.3f}s" for p in metrics.ROUND_PHASES
-                if isinstance(event.get(p), (int, float)))
-            line = (f"round {event.get('round')} "
-                    f"return={event.get('gs_return'):.3f} "
-                    f"ce={event.get('aip_ce_after'):.4f} "
-                    f"lag<={event.get('staleness_max')} "
-                    f"shards={event.get('n_shards')} "
-                    f"round_s={event.get('round_s'):.3f}"
-                    + (f" {phases}" if phases else ""))
-        elif kind == "host_death":
-            line = (f"host death at round {event.get('round')}: "
-                    f"dead={event.get('dead_hosts')}")
-        elif kind == "elastic_reassign":
-            line = (f"elastic replan: shards "
-                    f"{event.get('old_shards')}->{event.get('new_shards')}"
-                    f" moved={event.get('moved')}")
-        else:
-            payload = {k: v for k, v in event.items()
-                       if k not in metrics.ENVELOPE_FIELDS}
-            line = f"{kind} {payload}"
-        print(f"[{self.prefix} p{event.get('proc', 0)}] {line}")
-
-    def close(self) -> None:
-        pass
 
 
 class CsvSink:

@@ -1,32 +1,37 @@
-"""Lightweight span tracer for the DIALS runtime.
+"""Span tracer for the DIALS runtime.
 
 Three layers of the same idea — "name the time", at three costs:
 
-* **Host spans** (:class:`Tracer.span`) — nested context-manager spans on
-  a monotonic clock (``time.perf_counter``). Each span records
-  ``(name, depth, t0, dur_s)``; :meth:`Tracer.phase_seconds` aggregates
-  them into the per-phase seconds the typed round record
+* **Host spans** (:meth:`Tracer.span`) — nested spans, each entering a
+  ``jax.profiler.TraceAnnotation`` of its name whether or not telemetry
+  is on, so every span lands on the profiler's host plane, on the same
+  clock as the device's ``XLA Modules`` / ``XLA Ops`` events, whenever
+  a profiler session is live. With telemetry on, :class:`Tracer` also
+  records ``(name, depth, t0, dur_s)`` on a monotonic clock
+  (``time.perf_counter``); :meth:`Tracer.phase_seconds` aggregates them
+  into the per-phase seconds the typed round record
   (``repro.obs.metrics``) carries. JAX dispatch is asynchronous, so an
-  unfenced span around a jitted call measures *enqueue* time; pass
-  ``fence=True`` to the tracer and call ``sp.fence(outputs)`` inside the
-  span to ``jax.block_until_ready`` before the clock stops — honest
-  device timings, at the cost of a host sync per fenced span. The
-  drivers default to unfenced (their one-sync-per-round contract is
-  load-bearing); benchmarks fence.
+  unfenced span around a jitted call measures *enqueue* time; a tracer
+  built with ``fenced=True`` makes :meth:`Tracer.fence` call
+  ``jax.block_until_ready`` — honest device timings, at the cost of a
+  host sync per fence. The drivers default to unfenced.
+* **Host syncs** (:meth:`Tracer.pull`) — every blocking device-to-host
+  read of the drivers goes through ``pull(name, value)``, which performs
+  the read inside a span ``dials.sync.<name>`` (:data:`SYNC`). One such
+  span is one host sync: the profiler counts them, and
+  :meth:`Tracer.sync_seconds` sums their host seconds for the round
+  record's ``sync_s``.
 * **Trace-time annotations** (:func:`annotate`) — ``jax.named_scope``
   pass-through for code *inside* jitted programs (the per-shard train
   body, the halo exchange). Zero runtime cost: the scope names travel
   into HLO metadata so the regions are attributable in an XLA profile.
-* **Profiler sessions** (:func:`profile`) — an opt-in
-  ``jax.profiler.start_trace`` window (``--profile-dir`` on
-  ``benchmarks/run.py`` / ``benchmarks/scaling.py``); host spans
-  additionally enter ``jax.profiler.TraceAnnotation`` while a session
-  may be live, so the same span names land on the profiler timeline.
+
+:func:`profile` opens a ``jax.profiler`` session around a block.
 
 The disabled path is :data:`NULL_TRACER`: its :meth:`~NullTracer.span`
-returns one shared no-op span object (context entry is a constant-time
-attribute access, nothing is allocated or recorded), so leaving tracer
-calls in place costs nothing when telemetry is off.
+returns the bare ``TraceAnnotation`` — nothing is recorded and the
+tracer keeps no per-span state of its own; outside a profiler session
+a span costs about half a microsecond of host time (a TPU v5e host).
 """
 from __future__ import annotations
 
@@ -35,6 +40,9 @@ import time
 from typing import Dict, List, Optional
 
 import jax
+
+SYNC = "dials.sync."
+"""Name prefix of the spans around the drivers' device-to-host reads."""
 
 
 def annotate(name: str):
@@ -59,22 +67,6 @@ def profile(directory: Optional[str]):
         jax.profiler.stop_trace()
 
 
-class Span:
-    """One live span. ``fence(x)`` optionally blocks on device values so
-    the span's duration covers real execution, then returns ``x``."""
-
-    __slots__ = ("_tracer", "name", "depth", "t0")
-
-    def __init__(self, tracer: "Tracer", name: str, depth: int, t0: float):
-        self._tracer, self.name, self.depth, self.t0 = \
-            tracer, name, depth, t0
-
-    def fence(self, value):
-        if self._tracer.fenced:
-            jax.block_until_ready(value)
-        return value
-
-
 class Tracer:
     """Records nested host spans; see module docstring."""
 
@@ -93,9 +85,8 @@ class Tracer:
         depth, self._depth = self._depth, self._depth + 1
         with jax.profiler.TraceAnnotation(name):
             t0 = self._clock()
-            sp = Span(self, name, depth, t0)
             try:
-                yield sp
+                yield
             finally:
                 dur = self._clock() - t0
                 self._depth = depth
@@ -103,6 +94,19 @@ class Tracer:
                 # report/asserts re-nest via (t0, depth)
                 self.events.append({"name": name, "depth": depth,
                                     "t0": t0, "dur_s": dur})
+
+    def fence(self, value):
+        """``jax.block_until_ready(value)`` on a fenced tracer; returns
+        ``value`` either way."""
+        if self.fenced:
+            jax.block_until_ready(value)
+        return value
+
+    def pull(self, name: str, value, cast=float):
+        """The host-sync read ``cast(value)`` inside the span
+        ``dials.sync.<name>``."""
+        with self.span(SYNC + name):
+            return cast(value)
 
     def reset(self) -> None:
         self.events.clear()
@@ -116,26 +120,15 @@ class Tracer:
             out[e["name"]] = out.get(e["name"], 0.0) + e["dur_s"]
         return out
 
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    @staticmethod
-    def fence(value):
-        return value
-
-
-_NULL_SPAN = _NullSpan()
+    def sync_seconds(self) -> float:
+        """Host seconds inside ``dials.sync.*`` spans since the last
+        :meth:`reset`."""
+        return sum(e["dur_s"] for e in self.events
+                   if e["name"].startswith(SYNC))
 
 
 class NullTracer:
-    """Disabled tracer: one shared no-op span, no state, no recording."""
+    """Disabled tracer: profiler annotations only, no recording."""
 
     fenced = False
     events: List[Dict] = []       # intentionally shared + always empty
@@ -144,14 +137,27 @@ class NullTracer:
     def enabled(self) -> bool:
         return False
 
-    def span(self, name: str):
-        return _NULL_SPAN
+    @staticmethod
+    def span(name: str):
+        return jax.profiler.TraceAnnotation(name)
+
+    @staticmethod
+    def fence(value):
+        return value
+
+    @staticmethod
+    def pull(name: str, value, cast=float):
+        with jax.profiler.TraceAnnotation(SYNC + name):
+            return cast(value)
 
     def reset(self) -> None:
         pass
 
     def phase_seconds(self) -> Dict[str, float]:
         return {}
+
+    def sync_seconds(self) -> None:
+        return None
 
 
 NULL_TRACER = NullTracer()

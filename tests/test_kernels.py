@@ -233,6 +233,30 @@ def test_ssd_kernel_initial_state():
 
 # ---------------------------------------------------------------------------
 # GAE
+def _pallas_names(fn, *args):
+    """Names of the ``pallas_call``s in the jaxpr of ``fn(*args)``, read
+    from the analyzer's body paths (``pallas_call:<name>``)."""
+    from repro.analysis import walker
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return {c.split(":", 1)[1] for site in walker.walk(jaxpr.jaxpr)
+            for c in site.path if c.startswith("pallas_call:")}
+
+
+def test_gru_kernels_carry_their_names():
+    """The forward and backward GRU kernels are named, so that a profile
+    of the program shows ``gru_fwd`` / ``gru_bwd`` in their operations."""
+    params = gru_mod.gru_init(jax.random.PRNGKey(0),
+                              gru_mod.GRUConfig(in_dim=8, hidden=16))
+    xs = jnp.ones((2, 8, 8), jnp.float32)
+
+    def loss(p):
+        hs, _ = gru_ops.gru_sequence(p, xs, interpret=True)
+        return hs.sum()
+
+    assert _pallas_names(loss, params) == {"gru_fwd"}
+    assert _pallas_names(jax.grad(loss), params) == {"gru_fwd", "gru_bwd"}
+
+
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("shape", [(4, 16), (2, 3, 32), (8,)])
 def test_gae_kernel_matches_ref(shape):
@@ -302,6 +326,20 @@ def test_gae_kernel_lane_tiled_matches_ref(monkeypatch):
         rewards, values, last_value)
     np.testing.assert_allclose(float(vk), float(vr), rtol=1e-6)
     assert tree_maxdiff(gk, gr) < 1e-5
+
+
+def test_gae_kernels_carry_their_names():
+    """The GAE reverse scan and its adjoint are named ``gae_fwd`` and
+    ``gae_bwd``."""
+    x = jnp.ones((4, 16), jnp.float32)
+    last = jnp.ones((4,), jnp.float32)
+
+    def loss(r):
+        adv, _ = gae_ops.gae(r, x, 0 * x, last, interpret=True)
+        return adv.sum()
+
+    assert _pallas_names(loss, x) == {"gae_fwd"}
+    assert _pallas_names(jax.grad(loss), x) == {"gae_fwd", "gae_bwd"}
 
 
 def test_gae_oracle_traces_and_round_trips_bf16():

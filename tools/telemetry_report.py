@@ -5,8 +5,9 @@ merged on the fly if no ``telemetry.jsonl`` exists yet) or a single
 JSONL file. Output:
 
 * a per-round table — one line per round with the typed record's phase
-  seconds (``repro.obs.metrics.ROUND_FIELDS``), CE, staleness
-  distribution, and mesh size;
+  seconds (``repro.obs.metrics.ROUND_FIELDS``), the host seconds of its
+  device-to-host reads (``sync_s``), CE, staleness distribution, and
+  mesh size;
 * an elasticity timeline — every ``host_death`` / ``elastic_reassign``
   event plus the rounds where the mesh size changed, with the
   availability-tax ``mirror_s`` (the per-round host-mirror
@@ -69,7 +70,7 @@ def round_table(events: List[Dict]) -> str:
         return "(no round events)"
     cols = ("round", "gs_return", "aip_ce_after", "staleness_max",
             "n_shards", "collect_s", "env_steps_per_s", "aip_s",
-            "inner_s", "eval_s", "mirror_s", "round_s")
+            "inner_s", "eval_s", "mirror_s", "sync_s", "round_s")
     widths = {"aip_ce_after": 13, "env_steps_per_s": 15}
     lines = [" ".join(c.rjust(widths.get(c, 9)) for c in cols)]
     for rnd in sorted(per_round):
