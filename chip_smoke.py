@@ -15,7 +15,10 @@ Run from the root of a checkout, in one process that owns the chip:
                  intersections, the paper's largest traffic network) with
                  the default FNN policy (256, 128) and FNN AIP (128, 128).
 (d) warehouse    ``DIALSTrainer.run`` on warehouse side 5 (25 robots) with
-                 a GRU AIP (H=64) and a GRU policy (H=128).
+                 a GRU AIP (H=64) and a GRU policy (H=128); prints every
+                 GRU launch its programs traced (kernel, agents, agents a
+                 grid step, grid steps) and fails if a launch of several
+                 agents advances one agent a grid step.
 (e) four chips   traffic side 8 (64 agents, 4 row bands) through the
                  agent-sharded fused round with the region-decomposed GS
                  (``shards=4, sharded_gs="on"``) against the same config
@@ -55,6 +58,7 @@ from repro import compile_cache                             # noqa: E402
 from repro.core import dials, influence                     # noqa: E402
 from repro.envs import registry                             # noqa: E402
 from repro.kernels.gae import ops as gae_ops                # noqa: E402
+from repro.kernels.gru import kernel as gru_kernel          # noqa: E402
 from repro.kernels.gru import ops as gru_ops                # noqa: E402
 from repro.marl import gae as gae_mod                       # noqa: E402
 from repro.marl import policy, ppo                          # noqa: E402
@@ -320,6 +324,20 @@ def train_phase(label: str, setup, seed: int, *, n_shards: int = 1,
     return trainer, state, hist
 
 
+def launch_report(before: dict) -> None:
+    """The GRU launches traced since ``before`` (a ``launch_stats``);
+    each launch of several agents has to advance several a grid step."""
+    stats = gru_kernel.launch_stats()
+    new = {k: n - before.get(k, 0) for k, n in stats.items()
+           if n > before.get(k, 0)}
+    for (name, a, blk, steps), n in sorted(new.items()):
+        print(f"    {name}: {n} launch(es), {a} agents, {blk} a grid "
+              f"step, {steps} grid steps", flush=True)
+    check(bool(new), "no GRU launch was traced")
+    check(all(blk > 1 for _, a, blk, _ in new if a > 1),
+          "a GRU launch of several agents advances one a grid step")
+
+
 # ---------------------------------------------------------------------------
 # (e) four chips
 # ---------------------------------------------------------------------------
@@ -396,7 +414,9 @@ def main() -> int:
             print("(c) traffic", flush=True)
             train_phase("traffic", traffic, args.seed)
             print("(d) warehouse", flush=True)
+            before = gru_kernel.launch_stats()
             train_phase("warehouse", warehouse, args.seed)
+            launch_report(before)
     except SmokeFailure as e:
         print(f"FAIL: {e}", file=sys.stderr)
         return 1

@@ -357,6 +357,11 @@ def kernel_dtype_programs(dtype=jnp.bfloat16) -> List[Program]:
     }
     kernel_gae = functools.partial(gae_ops.gae, interpret=True)
     kernel_gru = functools.partial(gru_ops.gru_sequence, interpret=True)
+    # the agent-blocked launch: per-agent params, as DIALS maps them
+    n_agents = 3
+    agent_params = {k: jax.ShapeDtypeStruct((n_agents,) + v.shape, dtype)
+                    for k, v in gru_params.items()}
+    agent_xs = jax.ShapeDtypeStruct((n_agents,) + xs.shape, dtype)
     return [
         Program(name="kernels/gae/oracle", roles=("dtype",),
                 fn=gae_oracle.gae, args=gae_args),
@@ -366,6 +371,8 @@ def kernel_dtype_programs(dtype=jnp.bfloat16) -> List[Program]:
                 fn=gru_oracle.gru_sequence, args=(gru_params, xs)),
         Program(name="kernels/gru/pallas", roles=("dtype",),
                 fn=kernel_gru, args=(gru_params, xs)),
+        Program(name="kernels/gru/pallas-vmapped", roles=("dtype",),
+                fn=jax.vmap(kernel_gru), args=(agent_params, agent_xs)),
     ]
 
 

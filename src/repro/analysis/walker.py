@@ -8,7 +8,7 @@ structured traversal: every primitive occurrence becomes a
 
 * the **structural path** from the program root — which ``pjit`` /
   ``shard_map`` / ``scan`` / ``cond`` / ``while`` / ``custom_vjp`` /
-  ``pallas_call`` bodies enclose it (e.g.
+  ``custom_vmap`` / ``pallas_call`` bodies enclose it (e.g.
   ``pjit:train_fn / shard_map / scan``);
 * the **named-scope labels** active at trace time
   (``jax.named_scope`` — the ``shard_train`` / ``gs_collect`` /
@@ -61,6 +61,7 @@ _KNOWN_BODY_PARAMS = {
     "custom_jvp_call": ("call_jaxpr", "jvp_jaxpr_fun"),
     "custom_vjp_call": ("call_jaxpr", "fun_jaxpr"),
     "custom_vjp_call_jaxpr": ("fun_jaxpr",),
+    "custom_vmap_call": ("call",),
     "checkpoint": ("jaxpr",),
     "remat2": ("jaxpr",),
 }

@@ -23,6 +23,8 @@ GAE through them — resolved once per call site by
 On TPU the kernels compile through Mosaic (``tests/test_tpu_compile.py``
 compiles the DIALS ones for a described v5e chip); on any other backend
 they execute with ``interpret=True``. ``layout.batch_major`` keeps a
-``vmap``'d agent axis out of the blocks' tiled last two dims.
+``vmap``'d agent axis out of the GAE blocks' tiled last two dims; the
+GRU launches fold it into an agent axis of their own, so that one grid
+step advances a block of agents.
 """
 from repro.kernels import dispatch, flash_attention, gae, gru, ssd  # noqa: F401

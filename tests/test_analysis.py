@@ -80,6 +80,44 @@ def test_walker_sees_pallas_kernel_body():
     assert {"mul", "add"} <= {s.prim for s in inside}
 
 
+def test_walker_sees_gru_kernel_under_custom_vmap():
+    """The GRU launch sits in a ``custom_vmap``: unbatched, the kernel
+    lies in the ``custom_vmap_call``'s ``call`` body (a known body
+    parameter); vmapped, the rule's launch replaces it. The vmapped
+    program registered for the dtype contract launches the kernel and
+    passes DtypeRoundTrip."""
+    from repro.analysis import programs
+    from repro.kernels.gru import ops as gru_ops
+    d_in, h = 4, 8
+    params = {"wi": jnp.ones((d_in, 3 * h)), "wh": jnp.ones((h, 3 * h)),
+              "bi": jnp.ones((3 * h,)), "bh": jnp.ones((3 * h,))}
+    jaxpr = jax.make_jaxpr(lambda p, x: gru_ops.gru_sequence(
+        p, x, interpret=True))(params, jnp.ones((2, 5, d_in)))
+    inside = [s for s in walker.walk(jaxpr)
+              if "custom_vmap_call" in s.path
+              and "pallas_call:gru_fwd" in s.path]
+    assert inside, "walker did not descend into the custom_vmap body"
+    vmap_eqns = [e for e in _eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "custom_vmap_call"]
+    assert vmap_eqns and all(
+        [label for label, _ in walker.sub_jaxprs(e)] == ["call"]
+        for e in vmap_eqns)
+
+    prog, = [p for p in programs.kernel_dtype_programs()
+             if p.name == "kernels/gru/pallas-vmapped"]
+    paths = {c for s in walker.walk(jax.make_jaxpr(prog.fn)(*prog.args))
+             for c in s.path}
+    assert "pallas_call:gru_fwd" in paths
+    assert contracts.DtypeRoundTrip().check(prog) == []
+
+
+def _eqns(jaxpr):
+    for eqn in walker.raw_jaxpr(jaxpr).eqns:
+        yield eqn
+        for _, sub in walker.sub_jaxprs(eqn):
+            yield from _eqns(sub)
+
+
 def test_walker_fingerprint_detects_structural_change():
     mesh = runtime.shard_mesh(1)
 

@@ -14,6 +14,7 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.gae import ops as gae_ops
 from repro.kernels.gae import ref as gae_ref
+from repro.kernels.gru import kernel as gru_kernel
 from repro.kernels.gru import ops as gru_ops
 from repro.kernels.gru import ref as gru_ref
 from repro.kernels.ssd import ops as ssd_ops
@@ -166,6 +167,172 @@ def test_gru_kernel_grad_under_vmap():
     gr = one(lambda p, x, h0_, r: gru_ref.gru_sequence(
         p, x, h0_, reset_mask=r))(params, xs, h0, resets)
     assert tree_maxdiff(gk, gr) < 1e-6
+
+
+def _stacked_gru_params(key, agents, din, h):
+    """GRU params stacked over the leading ``agents`` axes."""
+    n = int(np.prod(agents))
+    p = jax.vmap(lambda k: gru_mod.gru_init(
+        k, gru_mod.GRUConfig(in_dim=din, hidden=h)))(jax.random.split(key, n))
+    return jax.tree.map(lambda x: x.reshape(agents + x.shape[1:]), p)
+
+
+# (case, agent axes (two: a nested vmap), B, T, agent axis of xs/h0/g,
+# with resets, VMEM budget or None). The budget of 168 KiB gives 3 agents
+# a forward step and 2 a backward one (B=3, H=8: every block slice is one
+# 4 KiB tile), so A=5 is zero-padded in both.
+GRU_VMAP_CASES = [
+    ("ragged-blocks", (5,), 3, 6, 0, False, 168 * 1024),
+    ("in-axes-1", (4,), 3, 5, 1, False, None),
+    ("nested-vmap", (2, 3), 3, 5, 0, False, None),
+    ("t1", (5,), 4, 1, 0, False, None),
+    ("odd-b7", (3,), 7, 4, 0, False, None),
+    ("resets", (4,), 3, 8, 0, True, None),
+]
+
+
+@pytest.mark.parametrize("case,agents,b,t,axis,with_resets,budget",
+                         GRU_VMAP_CASES, ids=[c[0] for c in GRU_VMAP_CASES])
+def test_gru_kernel_vmapped_matches_ref(monkeypatch, case, agents, b, t,
+                                        axis, with_resets, budget):
+    """The agent-blocked launch under vmap (per-agent params, as DIALS
+    calls it): forward and grads w.r.t. params, xs and h0 match the
+    oracle per agent."""
+    if budget:
+        monkeypatch.setattr(gru_kernel, "_VMEM_BUDGET", budget)
+    din, h = 5, 8
+    ks = jax.random.split(jax.random.PRNGKey(21), 5)
+    params = _stacked_gru_params(ks[0], agents, din, h)
+    n = agents[-1]
+    lead = agents[:-1] + ((b, n) if axis else (n, b))
+    xs = jax.random.normal(ks[1], lead + (t, din))
+    h0 = jax.random.normal(ks[2], lead + (h,))
+    g = jax.random.normal(ks[3], lead + (t, h))
+    resets = (jax.random.bernoulli(ks[4], 0.3, lead + (t,))
+              .astype(jnp.float32) if with_resets else None)
+
+    def run(seq_fn):
+        def f(p, x, h0_, g_, r):
+            hs, last = seq_fn(p, x, h0_, r)
+            return (hs * g_).sum() + (last ** 2).sum()
+        fn = jax.vmap(jax.value_and_grad(f, argnums=(0, 1, 2)),
+                      in_axes=(0, axis, axis, axis,
+                               axis if with_resets else None))
+        for _ in agents[:-1]:
+            fn = jax.vmap(fn)
+        return jax.jit(fn)(params, xs, h0, g, resets)
+
+    before = gru_kernel.launch_stats()
+    got = run(lambda p, x, h0_, r: gru_ops.gru_sequence(
+        p, x, h0_, reset_mask=r, interpret=True))
+    want = run(lambda p, x, h0_, r: gru_ref.gru_sequence(
+        p, x, h0_, reset_mask=r))
+    assert tree_maxdiff(got, want) < 1e-5
+    new = {k for k, c in gru_kernel.launch_stats().items()
+           if c > before.get(k, 0)}
+    # every agent of every vmapped axis rides in one launch each way
+    assert {(k, a) for k, a, _, _ in new} == {
+        ("gru_fwd", int(np.prod(agents))), ("gru_bwd", int(np.prod(agents)))}
+    if budget:
+        assert {(k, blk) for k, _, blk, _ in new} == {
+            ("gru_fwd", 3), ("gru_bwd", 2)}
+
+
+def _pallas_eqns(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, bodies included."""
+    from repro.analysis import walker
+    for eqn in walker.raw_jaxpr(jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for _, sub in walker.sub_jaxprs(eqn):
+            yield from _pallas_eqns(sub)
+
+
+def test_gru_vmapped_launch_is_agent_blocked():
+    """At warehouse side 10's AIP shapes (A=100 agents, B=7, T=128,
+    H=64) a vmapped sequence and its grad launch ``gru_fwd`` and
+    ``gru_bwd`` once each, over ceil(A / A_blk) x T grid steps with
+    A_blk > 1, not A x T. The launches keep the operand signatures the
+    benchmark's trace reader knows them by: gi first, 5 -> 1 with
+    gi's last axis 3x the result's, and 6 -> 4."""
+    a, b, t, din, h = 100, 7, 128, 16, 64
+    params = jax.eval_shape(
+        lambda: _stacked_gru_params(jax.random.PRNGKey(0), (a,), din, h))
+    xs = jax.ShapeDtypeStruct((a, b, t, din), jnp.float32)
+
+    def loss(p, x):
+        hs, _ = gru_ops.gru_sequence(p, x, interpret=True)
+        return (hs ** 2).sum()
+
+    before = gru_kernel.launch_stats()
+    jaxpr = jax.make_jaxpr(jax.vmap(jax.grad(loss)))(params, xs)
+    eqns = {e.params["name"]: e
+            for e in _pallas_eqns(jaxpr)}
+    assert sorted(eqns) == ["gru_bwd", "gru_fwd"]
+    blocks = {}
+    for name, sig in (("gru_fwd", (5, 1)), ("gru_bwd", (6, 4))):
+        eqn = eqns[name]
+        assert (len(eqn.invars), len(eqn.outvars)) == sig
+        gi = eqn.invars[0].aval.shape                 # (A padded, T, B, 3H)
+        assert gi[1:] == (t, b, 3 * h)
+        n_blocks, steps = eqn.params["grid_mapping"].grid
+        assert steps == t and gi[0] % n_blocks == 0
+        blk = gi[0] // n_blocks
+        assert 1 < blk and gi[0] - a < blk
+        blocks[name] = (blk, n_blocks * t)
+    assert eqns["gru_fwd"].outvars[0].aval.shape[-1] == h
+    assert blocks == {"gru_fwd": (34, 3 * t), "gru_bwd": (20, 5 * t)}
+    after = gru_kernel.launch_stats()
+    assert {k: c - before.get(k, 0) for k, c in after.items()
+            if c != before.get(k, 0)} == {
+        ("gru_fwd", a, 34, 3 * t): 1, ("gru_bwd", a, 20, 5 * t): 1}
+
+
+def test_dials_round_launches_every_gru_agent_blocked():
+    """The launch tally over a whole kernelized DIALS round (warehouse,
+    GRU AIP and policy, the agent-sharded runner's per-shard body on
+    one shard): every GRU launch, AIP training, PPO, the T=1 rollout
+    and collect cells, carries all 4 agents in one agent block."""
+    from repro.core import dials, dials_sharded, influence
+    from repro.envs import registry
+    from repro.marl import policy as policy_mod, ppo as ppo_mod
+    env_mod, env_cfg = registry.make("warehouse", side=2, horizon=16)
+    info = env_cfg.info()
+    pc = policy_mod.PolicyConfig(obs_dim=info.obs_dim,
+                                 n_actions=info.n_actions, kind="gru",
+                                 hidden=(16,), gru_hidden=8)
+    ac = influence.AIPConfig(in_dim=info.alsh_dim,
+                             n_sources=info.n_influence, kind="gru",
+                             hidden=(16,), gru_hidden=8, epochs=2, batch=8)
+    runner = dials_sharded.ShardedDIALSRunner(
+        env_mod, env_cfg, pc, ac, ppo_mod.PPOConfig(epochs=1, minibatches=2),
+        dials.DIALSConfig(outer_rounds=1, aip_refresh=2, collect_envs=2,
+                          collect_steps=8, n_envs=2, rollout_steps=8,
+                          use_kernels="on"),
+        n_shards=1)
+    before = gru_kernel.launch_stats()
+    jaxpr = runner.inner_jaxpr()
+    new = {k: c - before.get(k, 0)
+           for k, c in gru_kernel.launch_stats().items()
+           if c > before.get(k, 0)}
+    assert {k[0] for k in new} == {"gru_fwd", "gru_bwd"}
+    assert all((a, blk) == (info.n_agents, info.n_agents)
+               for _, a, blk, _ in new), new
+    grids = [e.params["grid_mapping"].grid for e in _pallas_eqns(jaxpr)
+             if e.params["name"].startswith("gru_")]
+    assert grids and all(g[0] == 1 for g in grids), grids
+
+
+def test_gru_agent_block_fits_the_vmem_budget():
+    """A_blk: the fewest equal blocks that fit the budget. All 100
+    agents' H=128 W_h (double-buffered) cannot share one step."""
+    wh = 2 * gru_kernel._tile_bytes(128, 384)
+    assert 100 * wh > gru_kernel._VMEM_BUDGET
+    assert gru_kernel.agent_block(100, wh) == 20          # 5 blocks of 20
+    assert gru_kernel.agent_block(1, wh) == 1
+    assert gru_kernel.agent_block(100, 10 ** 9) == 1      # never 0
+    most = gru_kernel._VMEM_BUDGET // wh
+    assert gru_kernel.agent_block(most + 1, wh) == -(-(most + 1) // 2)
 
 
 @pytest.mark.parametrize("xs_dtype,h0_dtype,want", [

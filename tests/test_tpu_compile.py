@@ -10,16 +10,20 @@ Shapes are those of ``chip_smoke.py``'s configs at ``DIALSConfig``
 defaults (16 IALS streams × 16 steps, PPO minibatches of 4 streams,
 8 collect streams × 128 steps with one held out): traffic side 10
 (100 agents) and warehouse side 5 (25 agents; GRU policy H=128 and GRU
-AIP H=64 over 128-wide trunks), plus an odd batch for each kernel and a
-GAE batch wide enough to be split into lane tiles. The GS collect's
-policy step maps the agent axis 1 of stream-major arrays; that case
-checks the kernels' ``layout.batch_major`` operands.
+AIP H=64 over 128-wide trunks), plus warehouse side 10's own GRU shapes
+(100 agents, the benchmark's ``warehouse10.f50``), an odd batch for each
+kernel and a GAE batch wide enough to be split into lane tiles. The GS
+collect's policy step maps the agent axis 1 of stream-major arrays;
+that case checks that the GRU launch's vmap rule moves the axis to the
+front. Every GRU launch keeps the operand signature the benchmark's
+trace reader knows it by.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,7 +99,42 @@ def _compile_with_params(fn, params, shapes, sharding):
 GRU_SEQ_CASES = [("warehouse-ppo-policy", 25, 4, 16, 128, 128),
                  ("warehouse-aip-train", 25, 7, 128, 128, 64),
                  ("warehouse-aip-eval", 25, 1, 128, 128, 64),
-                 ("odd-batch", 25, 6, 16, 128, 128)]
+                 ("odd-batch", 25, 6, 16, 128, 128),
+                 ("warehouse10-ppo-policy", 100, 4, 16, 128, 128),
+                 ("warehouse10-aip-train", 100, 7, 128, 128, 64)]
+
+_F32 = re.compile(r"f32\[([0-9,]*)\]")
+
+
+def _gru_signatures(hlo):
+    """(operand shapes, result shapes) of each Mosaic call in compiled
+    HLO text."""
+    out = []
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        head = line.split(" custom-call(")[0]
+        ops = re.search(r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
+                        line).group(1)
+        shapes = lambda text: [tuple(int(d) for d in m.split(",") if d)
+                               for m in _F32.findall(text)]
+        out.append((shapes(ops), shapes(head)))
+    return out
+
+
+def _assert_gru_signatures(hlo, want):
+    """The launches are ``want`` (``fwd``/``bwd`` names, in any order):
+    gi first, 5 -> 1 with gi's last axis 3x the result's; 6 -> 4."""
+    kinds = []
+    for ops, res in _gru_signatures(hlo):
+        if (len(ops), len(res)) == (5, 1):
+            assert ops[0][-1] == 3 * res[0][-1], (ops, res)
+            kinds.append("fwd")
+        else:
+            assert (len(ops), len(res)) == (6, 4), (ops, res)
+            assert ops[0] == res[0], (ops, res)       # gi and dgi
+            kinds.append("bwd")
+    assert sorted(kinds) == sorted(want)
 
 
 @pytest.mark.parametrize("case,n,b,t,din,h", GRU_SEQ_CASES,
@@ -113,6 +152,7 @@ def test_gru_sequence_grad_compiles_for_v5e(one_chip, case, n, b, t, din,
                                [(n, b, t, din), (n, b, h), (n, b, t)],
                                one_chip)
     assert "tpu_custom_call" in hlo
+    _assert_gru_signatures(hlo, ["fwd", "bwd"])
 
 
 # (case, agents, batch B, in, H, agent axis of h and x): the GS collect
@@ -120,7 +160,9 @@ def test_gru_sequence_grad_compiles_for_v5e(one_chip, case, n, b, t, din,
 GRU_CELL_CASES = [("warehouse-ials-policy", 25, 16, 128, 128, 0),
                   ("warehouse-ials-aip", 25, 16, 128, 64, 0),
                   ("warehouse-collect-policy", 25, 8, 128, 128, 1),
-                  ("odd-batch", 25, 6, 128, 128, 0)]
+                  ("odd-batch", 25, 6, 128, 128, 0),
+                  ("warehouse10-ials-policy", 100, 16, 128, 128, 0),
+                  ("warehouse10-collect-policy", 100, 8, 128, 128, 1)]
 
 
 @pytest.mark.parametrize("case,n,b,din,h,axis", GRU_CELL_CASES,
@@ -133,3 +175,4 @@ def test_gru_cell_compiles_for_v5e(one_chip, case, n, b, din, h, axis):
     hlo = _compile_with_params(cell, _gru_params(n, din, h),
                                [lead + (h,), lead + (din,)], one_chip)
     assert "tpu_custom_call" in hlo
+    _assert_gru_signatures(hlo, ["fwd"])
