@@ -4,13 +4,17 @@
 - a traffic mix: ``bench/traffic/<traffic>.json``;
 - a cell's correctness limits: ``bench/limits/<workload>.json``;
 - a per-layer metric's reader: ``bench/metrics/<metric>.py``, a module
-  with ``read(run) -> float | None``.
+  with ``read(run) -> float | None``;
+- what a configuration file names (``module``): its environment's plain
+  reference, ``bench/harness/ref/<env>.py``, and the backbone of each
+  network, ``bench/harness/ref/backbones/<kind>.py``.
 
-A later cell, traffic mix or metric is added as files and entries; no
-file here changes.
+A later cell, traffic mix, metric, environment or backbone is added as
+files and entries; no file here changes.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -61,3 +65,15 @@ def reader(metric: str, root: pathlib.Path = ROOT):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def module(package: str, name: str):
+    """The module ``<package>.<name>``, found by the name a configuration
+    gives it. Where the package holds no file ``<name>.py`` the KeyError
+    names the file to add."""
+    pkg = importlib.import_module(package)
+    path = pathlib.Path(list(pkg.__path__)[0]) / f"{name}.py"
+    if not (name.isidentifier() and path.is_file()):
+        raise KeyError(f"no {package} module named {name!r}: add "
+                       f"{path.relative_to(ROOT)}")
+    return importlib.import_module(f"{package}.{name}")

@@ -1,21 +1,26 @@
 """Operations and bytes a DIALS round needs, from the job's shapes alone.
 
-Two kinds of count:
+Every count follows ``network_passes``: each pass of the policy or the
+influence predictor (AIP) that one outer round of Algorithm 1 makes,
+over how many rows and steps, and whether it is differentiated. A
+network is an MLP trunk, a backbone and its heads; the backbone's own
+counts come from ``bench/harness/ref/backbones/<kind>.py``, found by the
+``kind`` the configuration names.
 
-- ``round_matmul_flops(job, info)``: the matmul FLOPs one outer round of
-  Algorithm 1 requires (2 per multiply-add), for ``round_mfu``. Every
-  network pass counts once: the policy forward in the GS collect, the
-  IALS rollouts, the bootstrap value and the GS eval; the AIP forward in
-  the rollouts and in the held-out CE before and after training; PPO and
-  AIP training forward and backward (backward = 2 x forward), over their
+- ``round_matmul_flops(job, info)``: the matmul FLOPs one round
+  requires (2 per multiply-add), for ``round_mfu``. Every network pass
+  counts once: the policy forward in the GS collect, the IALS rollouts,
+  the bootstrap value and the GS eval; the AIP forward in the rollouts
+  and in the held-out CE before and after training; PPO and AIP
+  training forward and backward (backward = 2 x forward), over their
   real epochs and minibatches. Work a program repeats (each shard
-  re-running a replicated GS) or recomputes (a GRU backward that redoes
-  the forward) is not needed, so it does not count.
-- ``gae_ops`` / ``gru_ops``: the kernel operations of one round, each as
-  ``(flops, bytes)`` of the operation itself: the GAE reverse scan (adv
-  from rewards, values, next values and dones) and the GRU recurrence
-  (hidden states from precomputed gate inputs ``gi``), forward and
-  backward. They are the work of the operation, not of an
+  re-running a replicated GS) or recomputes (a recurrent backward that
+  redoes the forward) is not needed, so it does not count.
+- ``kernel_ops(kind, job, info)``: the operations of one round's
+  kernels of a backbone kind (named ``<kind>_fwd`` / ``<kind>_bwd`` in
+  the program), and ``gae_ops``: those of the GAE reverse scan (adv
+  from rewards, values, next values and dones), which is no backbone.
+  Each is ``(flops, bytes)`` of the operation itself, not of an
   implementation: a kernel that fuses more (the input projection, the
   returns) does more than is counted and reads a lower share, never a
   higher one.
@@ -24,6 +29,8 @@ Two kinds of count:
 alsh_dim, horizon`` (the environment's static facts).
 """
 from __future__ import annotations
+
+from .ref import backbones
 
 F32 = 4
 
@@ -36,25 +43,20 @@ def _mlp_flops(din, hidden):
     return f, d
 
 
+def _net_flops(net, din, dout) -> int:
+    f, d = _mlp_flops(din, net["hidden"])
+    core, d = backbones.get(net["kind"]).fwd_flops(d, net)
+    return f + core + 2 * d * dout
+
+
 def policy_fwd_flops(job, info) -> int:
-    """Matmul FLOPs of one policy step for one agent and one stream."""
-    p = job["policy"]
-    f, d = _mlp_flops(info.obs_dim, p["hidden"])
-    if p["kind"] == "gru":
-        h = p["gru_hidden"]
-        f += 2 * d * 3 * h + 2 * h * 3 * h
-        d = h
-    return f + 2 * d * (info.n_actions + 1)
+    """Matmul FLOPs of one policy step for one agent and one stream: the
+    trunk, the backbone, and the logits and value heads."""
+    return _net_flops(job["policy"], info.obs_dim, info.n_actions + 1)
 
 
 def aip_fwd_flops(job, info) -> int:
-    a = job["aip"]
-    f, d = _mlp_flops(info.alsh_dim, a["hidden"])
-    if a["kind"] == "gru":
-        h = a["gru_hidden"]
-        f += 2 * d * 3 * h + 2 * h * 3 * h
-        d = h
-    return f + 2 * d * info.n_influence
+    return _net_flops(job["aip"], info.alsh_dim, info.n_influence)
 
 
 def _aip_shapes(job):
@@ -71,23 +73,54 @@ def _ppo_shapes(job):
     return max(1, e // mbs), mbs
 
 
-def round_matmul_flops(job, info) -> float:
+def network_passes(job, info):
+    """Every network pass of one round, in the order of the round, as
+    ``(network, rows, steps, backward, times)``: ``network`` is
+    ``"policy"`` or ``"aip"``, the key of its configuration in the job;
+    a single step is a pass of one step."""
     n, tc = info.n_agents, job["collect_steps"]
-    pol, aip = policy_fwd_flops(job, info), aip_fwd_flops(job, info)
     e, t, f = job["ials_streams"], job["rollout_steps"], job["aip_refresh"]
-    collect = job["collect_streams"] * tc * n * pol
-    n_eval, batch, n_mb = _aip_shapes(job)
-    aip_round = (2 * n_eval * tc * n * aip
-                 + job["aip_train"]["epochs"] * n_mb * batch * tc * n
-                 * 3 * aip)
     mb, mbs = _ppo_shapes(job)
-    inner = f * n * (e * t * (pol + aip) + e * pol
-                     + job["ppo"]["epochs"] * mbs * mb * t * 3 * pol)
-    evaluate = job["eval_episodes"] * info.horizon * n * pol
-    return float(collect + aip_round + inner + evaluate)
+    n_eval, batch, n_mb = _aip_shapes(job)
+    return [
+        # GS collect
+        ("policy", n * job["collect_streams"], 1, False, tc),
+        # IALS rollouts and the bootstrap value, F inner steps
+        ("policy", n * e, 1, False, (t + 1) * f),
+        # PPO, every minibatch of every epoch of the F inner steps
+        ("policy", n * mb, t, True, f * job["ppo"]["epochs"] * mbs),
+        # GS eval
+        ("policy", n * job["eval_episodes"], 1, False, info.horizon),
+        # IALS rollouts
+        ("aip", n * e, 1, False, t * f),
+        # held-out CE before and after training
+        ("aip", n * n_eval, tc, False, 2),
+        # AIP training, every minibatch of every epoch
+        ("aip", n * batch, tc, True, job["aip_train"]["epochs"] * n_mb),
+    ]
 
 
-# -- kernel operations --------------------------------------------------------
+def round_matmul_flops(job, info) -> float:
+    step = {"policy": policy_fwd_flops(job, info),
+            "aip": aip_fwd_flops(job, info)}
+    return float(sum(rows * steps * (3 if backward else 1) * times
+                     * step[net]
+                     for net, rows, steps, backward, times
+                     in network_passes(job, info)))
+
+
+def kernel_ops(kind: str, job, info):
+    """The kernel operations of one round of every network whose
+    backbone is ``kind``, in the order of ``network_passes``. A kind with
+    no file under ``bench/harness/ref/backbones/`` raises."""
+    backbone, ops = backbones.get(kind), []
+    for net, rows, steps, backward, times in network_passes(job, info):
+        if job[net]["kind"] == kind:
+            ops += backbone.kernel_ops(job[net], rows, steps,
+                                       backward) * times
+    return ops
+
+
 def gae_fwd(b: int, t: int):
     """(flops, bytes) of the GAE reverse scan over b rows of t steps:
     delta = r + g*nv*(1-d) - v (5 flops), adv = delta + g*l*(1-d)*carry
@@ -95,56 +128,8 @@ def gae_fwd(b: int, t: int):
     return 8.0 * b * t, F32 * 5.0 * b * t
 
 
-def gru_fwd(b: int, t: int, h: int):
-    """(flops, bytes) of the GRU recurrence over b rows and t steps with
-    hidden size h: per row and step gh = h_prev @ W_h (2*h*3h) and ~14h
-    elementwise (gates, reset mask, update); reads gi (3h), the reset
-    flag, W_h, b_h, h0 and writes hs (h)."""
-    flops = b * t * (6.0 * h * h + 14.0 * h)
-    bytes_ = F32 * (b * t * (3 * h + 1 + h) + 3 * h * h + 3 * h + b * h)
-    return flops, bytes_
-
-
-def gru_bwd(b: int, t: int, h: int):
-    """(flops, bytes) of the recurrence's backward: per row and step the
-    two adjoint matmuls dh_prev = dgh @ W_h^T and dW_h += h_prev^T dgh
-    (2 x 2*h*3h) and ~30h elementwise; the forward it recomputes does not
-    count. Reads gi, h_prev, the cotangent, the reset flag and W_h, b_h;
-    writes dgi (3h per row and step), dW_h, db_h and dh0."""
-    flops = b * t * (12.0 * h * h + 30.0 * h)
-    bytes_ = F32 * (b * t * (3 * h + h + h + 1 + 3 * h)
-                    + 2 * (3 * h * h + 3 * h) + b * h)
-    return flops, bytes_
-
-
 def gae_ops(job, info):
     """The GAE kernel operations of one round: one forward per inner step
     over all agents' streams (PPO does not differentiate through GAE)."""
     b = info.n_agents * job["ials_streams"]
     return [gae_fwd(b, job["rollout_steps"])] * job["aip_refresh"]
-
-
-def gru_ops(job, info):
-    """The GRU recurrence operations of one round, forward and backward,
-    for every network that is a GRU. A single policy or AIP step is a
-    recurrence of length 1."""
-    n, ops = info.n_agents, []
-    p, a = job["policy"], job["aip"]
-    e, t, f = job["ials_streams"], job["rollout_steps"], job["aip_refresh"]
-    if p["kind"] == "gru":
-        hp = p["gru_hidden"]
-        ops += [gru_fwd(n * job["collect_streams"], 1, hp)] \
-            * job["collect_steps"]
-        ops += [gru_fwd(n * e, 1, hp)] * ((t + 1) * f)
-        mb, mbs = _ppo_shapes(job)
-        k = f * job["ppo"]["epochs"] * mbs
-        ops += [gru_fwd(n * mb, t, hp), gru_bwd(n * mb, t, hp)] * k
-        ops += [gru_fwd(n * job["eval_episodes"], 1, hp)] * info.horizon
-    if a["kind"] == "gru":
-        ha, tc = a["gru_hidden"], job["collect_steps"]
-        ops += [gru_fwd(n * e, 1, ha)] * (t * f)
-        n_eval, batch, n_mb = _aip_shapes(job)
-        ops += [gru_fwd(n * n_eval, tc, ha)] * 2
-        k = job["aip_train"]["epochs"] * n_mb
-        ops += [gru_fwd(n * batch, tc, ha), gru_bwd(n * batch, tc, ha)] * k
-    return ops

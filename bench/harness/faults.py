@@ -8,10 +8,13 @@ restores them after:
 - ``half_batch``: PPO's loss is the mean over half of each minibatch;
 - ``answer_altered``: the GS collect writes the opposite influence bits
   into the dataset it produces;
-- ``exchange_dropped``: on a mesh of several chips (traffic), the GS
-  collect's influence bits that cross chips, an incoming lane fed by a
-  neighbour whose agents live on another chip, arrive as zeros: what
-  the dataset holds if the exchange between chips is left out.
+- ``exchange_dropped``: on a mesh of several chips, the GS collect's
+  influence bits that cross chips arrive as zeros: what the dataset
+  holds if the exchange between chips is left out. Which bits cross
+  chips is the environment's to say: its plain reference
+  (``ref/<env>.py``) gives them as ``cross_chip_mask(env_cfg,
+  n_shards)``, and an environment without it cannot have this fault
+  planted.
 
 Used by ``bench/calibrate.py --fault`` on the chip and by the
 benchmark's tests on the CPU; the benchmark's own runs never plant one.
@@ -22,8 +25,8 @@ import contextlib
 import functools
 
 import jax
-import jax.numpy as jnp
-import numpy as np
+
+from . import catalog
 
 
 @contextlib.contextmanager
@@ -92,20 +95,13 @@ def exchange_dropped():
 
 
 def _cross_chip_mask(runner):
-    """(N, 1, 1, 4) zeros where a traffic agent's incoming lane [N, E, S,
-    W] is fed by a neighbour whose agent block lies on another chip."""
-    side = runner.env_cfg.n
-    n = side * side
-    block = n // runner.n_shards
-    mask = np.ones((n, 4), np.float32)
-    for i in range(n):
-        r, c = divmod(i, side)
-        for lane, (dr, dc) in enumerate(((-1, 0), (0, 1), (1, 0), (0, -1))):
-            rr, cc = r + dr, c + dc
-            if 0 <= rr < side and 0 <= cc < side and \
-                    (rr * side + cc) // block != i // block:
-                mask[i, lane] = 0.0
-    return jnp.asarray(mask[:, None, None, :])
+    env = catalog.module(f"{__package__}.ref", runner.info.name)
+    if not hasattr(env, "cross_chip_mask"):
+        raise NotImplementedError(
+            f"{env.__name__} has no cross_chip_mask(env_cfg, n_shards): "
+            f"the exchange between chips cannot be dropped for "
+            f"{runner.info.name!r}")
+    return env.cross_chip_mask(runner.env_cfg, runner.n_shards)
 
 
 FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,

@@ -44,6 +44,14 @@ def agent_steps_per_round(job: dict, n_agents: int) -> int:
             * job["aip_refresh"])
 
 
+def _network_keys(net: dict) -> dict:
+    """Every key of a configuration's ``policy`` or ``aip`` object, as
+    the program's network config takes it (lists as tuples). A key the
+    program's config lacks fails where the config is built."""
+    return {k: tuple(v) if isinstance(v, list) else v
+            for k, v in net.items()}
+
+
 def program(job: dict, outer_rounds: int):
     """The program's own objects for this job:
     ``(env_mod, env_cfg, policy_cfg, aip_cfg, ppo_cfg, dials_cfg)``.
@@ -55,15 +63,11 @@ def program(job: dict, outer_rounds: int):
 
     env_mod, env_cfg = registry.make(job["env"], side=job["side"])
     info = env_cfg.info()
-    pn, an = job["policy"], job["aip"]
     pc = policy.PolicyConfig(obs_dim=info.obs_dim, n_actions=info.n_actions,
-                             kind=pn["kind"], hidden=tuple(pn["hidden"]),
-                             gru_hidden=pn["gru_hidden"])
+                             **_network_keys(job["policy"]))
     ac = influence.AIPConfig(in_dim=info.alsh_dim,
-                             n_sources=info.n_influence, kind=an["kind"],
-                             hidden=tuple(an["hidden"]),
-                             gru_hidden=an["gru_hidden"],
-                             **job["aip_train"])
+                             n_sources=info.n_influence,
+                             **_network_keys(job["aip"]), **job["aip_train"])
     ppo_cfg = ppo.PPOConfig(**job["ppo"])
     cfg = dials.DIALSConfig(
         aip_refresh=job["aip_refresh"], outer_rounds=outer_rounds,

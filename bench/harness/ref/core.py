@@ -7,7 +7,11 @@ version, with the same key derivations, so that a program that is right
 draws the same random numbers and differs only by rounding.
 
 ``Ref(job, dtype)`` builds it for one job (a dict made by
-``harness.job``); every method is jitted.
+``harness.job``); every method is jitted. The environment is the plain
+reference that the job's ``env`` names, ``ref/<env>.py``: a module with
+``config_from_side(side)`` and the environment protocol's functions
+(``gs_*`` for the global simulator, ``ls_*`` for an agent's local one),
+so a new environment is a new file there.
 """
 from __future__ import annotations
 
@@ -16,11 +20,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import nets, optim, traffic, warehouse
+from .. import catalog
+from . import nets, optim
 
-ENVS = {"traffic": (traffic, lambda side: traffic.TrafficConfig(n=side)),
-        "warehouse": (warehouse,
-                      lambda side: warehouse.WarehouseConfig(k=side))}
+
+def env_module(name: str):
+    """The plain reference of environment ``name``."""
+    return catalog.module(__package__, name)
 
 
 def stream_keys(key, n):
@@ -118,8 +124,8 @@ class Ref:
 
     def __init__(self, job, dtype=jnp.float32):
         self.job, self.dtype = job, dtype
-        env_mod, make_cfg = ENVS[job["env"]]
-        self.env, self.env_cfg = env_mod, make_cfg(job["side"])
+        self.env = env_module(job["env"])
+        self.env_cfg = self.env.config_from_side(job["side"])
         info = self.env_cfg.info()
         self.n = info.n_agents
         self.n_actions = info.n_actions

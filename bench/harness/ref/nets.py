@@ -1,14 +1,16 @@
-"""Plain reference networks: the FNN/GRU policy and influence predictor
-(AIP), their initialisers and the AIP's loss, training and held-out CE,
-written in straightforward ``jax.numpy`` with no kernels.
+"""Plain reference networks: the policy and influence predictor (AIP),
+their initialisers and the AIP's loss, training and held-out CE, written
+in straightforward ``jax.numpy`` with no kernels.
 
-A copy of the program's jnp paths (``repro.nn.{init,gru}``,
-``repro.marl.policy``, ``repro.core.influence``) as of the benchmark's
-first version. Every function takes ``dtype``: the float type the
-networks compute in. The reference runs at float32 under ``highest``
-matmul precision; the precision control runs the same code at bfloat16
-(parameters and activations), which is what a lower-precision program
-would compute.
+A network is an MLP trunk, a backbone and its heads. The backbone is
+found by the ``kind`` its configuration names, in
+``backbones/<kind>.py``, so a new kind is a new file there. A copy of
+the program's jnp paths (``repro.nn.init``, ``repro.marl.policy``,
+``repro.core.influence``) as of the benchmark's first version. Every
+function takes ``dtype``: the float type the networks compute in. The
+reference runs at float32 under ``highest`` matmul precision; the
+precision control runs the same code at bfloat16 (parameters and
+activations), which is what a lower-precision program would compute.
 """
 from __future__ import annotations
 
@@ -17,20 +19,16 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import optim
+from . import backbones, optim
 
 
 # -- initialisers (repro.nn.init) --------------------------------------------
-def _normal(key, shape, stddev):
-    return stddev * jax.random.normal(key, shape, jnp.float32)
-
-
-def _fan_in_normal(key, shape):
+def fan_in_normal(key, shape):
     x = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
     return (1.0 / math.sqrt(max(shape[0], 1))) * x
 
 
-def _orthogonal(key, shape, scale=1.0):
+def orthogonal(key, shape, scale=1.0):
     rows, cols = shape[-2], shape[-1]
     n = max(rows, cols)
     flat = jax.random.normal(key, shape[:-2] + (n, n), jnp.float32)
@@ -40,52 +38,12 @@ def _orthogonal(key, shape, scale=1.0):
 
 
 def _dense_init(key, din, dout, scale):
-    return {"w": _orthogonal(key, (din, dout), scale),
+    return {"w": orthogonal(key, (din, dout), scale),
             "b": jnp.zeros((dout,), jnp.float32)}
 
 
 def _dense(p, x):
     return x @ p["w"] + p["b"]
-
-
-# -- GRU (repro.nn.gru, jnp path) --------------------------------------------
-def gru_init(key, din, hidden):
-    ki, kh = jax.random.split(key)
-    return {"wi": _fan_in_normal(ki, (din, 3 * hidden)),
-            "wh": _orthogonal(kh, (hidden, 3 * hidden)),
-            "bi": jnp.zeros((3 * hidden,), jnp.float32),
-            "bh": jnp.zeros((3 * hidden,), jnp.float32)}
-
-
-def _dot(x, w):
-    y = jax.lax.dot_general(x, w, (((x.ndim - 1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    return y.astype(x.dtype)
-
-
-def gru_cell(p, h, x):
-    gi = _dot(x, p["wi"]) + p["bi"].astype(x.dtype)
-    gh = _dot(h, p["wh"]) + p["bh"].astype(h.dtype)
-    i_r, i_z, i_n = jnp.split(gi, 3, axis=-1)
-    h_r, h_z, h_n = jnp.split(gh, 3, axis=-1)
-    r = jax.nn.sigmoid((i_r + h_r).astype(jnp.float32))
-    z = jax.nn.sigmoid((i_z + h_z).astype(jnp.float32))
-    n = jnp.tanh((i_n + r * h_n).astype(jnp.float32))
-    new_h = (1.0 - z) * n + z * h.astype(jnp.float32)
-    return new_h.astype(h.dtype)
-
-
-def gru_sequence(p, xs, h0, reset_mask):
-    def step(h, inp):
-        x, m = inp
-        h = h * (1.0 - m[:, None].astype(h.dtype))
-        h = gru_cell(p, h, x)
-        return h, h
-
-    xs_t = jnp.swapaxes(xs, 0, 1)
-    ms_t = jnp.swapaxes(reset_mask, 0, 1).astype(xs.dtype)
-    h_last, hs = jax.lax.scan(step, h0, (xs_t, ms_t))
-    return jnp.swapaxes(hs, 0, 1), h_last
 
 
 def cast(tree, dtype):
@@ -103,16 +61,15 @@ def policy_init(key, net, dtype):
         trunk.append(_dense_init(keys[i], din, h, math.sqrt(2.0)))
         din = h
     params["trunk"] = trunk
-    if net["kind"] == "gru":
-        params["gru"] = gru_init(keys[3], din, net["gru_hidden"])
-        din = net["gru_hidden"]
+    core, din = backbones.get(net["kind"]).init(keys[3], din, net)
+    params.update(core)
     params["pi"] = _dense_init(keys[4], din, net["n_actions"], 0.01)
     params["v"] = _dense_init(keys[5], din, 1, 1.0)
     return cast(params, dtype)
 
 
 def hidden0(net, *batch, dtype=jnp.float32):
-    return jnp.zeros(tuple(batch) + (net["gru_hidden"],), dtype)
+    return backbones.get(net["kind"]).hidden0(net, batch, dtype)
 
 
 def _trunk(params, x):
@@ -123,18 +80,13 @@ def _trunk(params, x):
 
 def policy_apply(params, obs, h, net):
     x = _trunk(params, obs.astype(params["pi"]["w"].dtype))
-    if net["kind"] == "gru":
-        flat = x.reshape(-1, x.shape[-1])
-        hf = gru_cell(params["gru"], h.reshape(-1, h.shape[-1]), flat)
-        h = hf.reshape(h.shape)
-        x = h
+    x, h = backbones.get(net["kind"]).step(params, x, h, net)
     return _dense(params["pi"], x), _dense(params["v"], x)[..., 0], h
 
 
 def policy_sequence(params, obs_seq, h0, reset_mask, net):
     x = _trunk(params, obs_seq.astype(params["pi"]["w"].dtype))
-    if net["kind"] == "gru":
-        x, _ = gru_sequence(params["gru"], x, h0, reset_mask)
+    x = backbones.get(net["kind"]).sequence(params, x, h0, reset_mask, net)
     return _dense(params["pi"], x), _dense(params["v"], x)[..., 0]
 
 
@@ -152,9 +104,8 @@ def aip_init(key, net, dtype):
         trunk.append(_dense_init(keys[i], din, h, math.sqrt(2.0)))
         din = h
     params["trunk"] = trunk
-    if net["kind"] == "gru":
-        params["gru"] = gru_init(keys[3], din, net["gru_hidden"])
-        din = net["gru_hidden"]
+    core, din = backbones.get(net["kind"]).init(keys[3], din, net)
+    params.update(core)
     params["heads"] = _dense_init(keys[4], din, net["n_sources"],
                                   math.sqrt(2.0))
     return cast(params, dtype)
@@ -162,18 +113,13 @@ def aip_init(key, net, dtype):
 
 def aip_apply(params, feat, h, net):
     x = _trunk(params, feat.astype(params["heads"]["w"].dtype))
-    if net["kind"] == "gru":
-        flat = x.reshape(-1, x.shape[-1])
-        hf = gru_cell(params["gru"], h.reshape(-1, h.shape[-1]), flat)
-        h = hf.reshape(h.shape)
-        x = h
+    x, h = backbones.get(net["kind"]).step(params, x, h, net)
     return _dense(params["heads"], x), h
 
 
 def aip_sequence(params, feats, h0, resets, net):
     x = _trunk(params, feats.astype(params["heads"]["w"].dtype))
-    if net["kind"] == "gru":
-        x, _ = gru_sequence(params["gru"], x, h0, resets)
+    x = backbones.get(net["kind"]).sequence(params, x, h0, resets, net)
     return _dense(params["heads"], x)
 
 

@@ -9,6 +9,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .base import EnvInfo, contiguous_partition
 
@@ -218,3 +219,28 @@ def ls_step(local, action, u, key, cfg: TrafficConfig):
 def ls_obs(local, cfg: TrafficConfig):
     return _obs(local["lanes"], local["phase"])
 
+
+
+def config_from_side(side: int) -> TrafficConfig:
+    """The configuration of a ``side`` x ``side`` grid of intersections."""
+    return TrafficConfig(n=side)
+
+
+def cross_chip_mask(cfg, n_shards: int):
+    """(N, 1, 1, 4) zeros where an agent's incoming lane [N, E, S, W] is
+    fed by a neighbour whose agent block lies on another of ``n_shards``
+    chips: what the influence bits of a GS collect hold if the exchange
+    between chips is left out (``harness.faults.exchange_dropped``).
+    ``cfg`` is any configuration with the grid side ``n``."""
+    side = cfg.n
+    n = side * side
+    block = n // n_shards
+    mask = np.ones((n, 4), np.float32)
+    for i in range(n):
+        r, c = divmod(i, side)
+        for lane, (dr, dc) in enumerate(((-1, 0), (0, 1), (1, 0), (0, -1))):
+            rr, cc = r + dr, c + dc
+            if 0 <= rr < side and 0 <= cc < side and \
+                    (rr * side + cc) // block != i // block:
+                mask[i, lane] = 0.0
+    return jnp.asarray(mask[:, None, None, :])

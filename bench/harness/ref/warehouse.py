@@ -242,3 +242,8 @@ def ls_step_given(local, action, u, spawn, cfg: WarehouseConfig):
 def ls_obs(local, cfg: WarehouseConfig):
     return _obs(local["pos"], local["ages"])
 
+
+
+def config_from_side(side: int) -> WarehouseConfig:
+    """The configuration of a ``side`` x ``side`` grid of robots."""
+    return WarehouseConfig(k=side)

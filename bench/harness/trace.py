@@ -13,11 +13,13 @@ at the two round boundaries that close the window. Device and host
 events share one clock.
 
 A Pallas kernel is an instruction with
-``custom_call_target="tpu_custom_call"``; the program gives its kernels
-no names, so ``kernel_kind`` tells them apart by their operands: the GAE
-reverse scan takes four equal arrays (its backward two, returning two),
-the GRU recurrence takes five (gate inputs ``gi`` whose last axis is
-three times that of its result) and its backward six, returning four.
+``custom_call_target="tpu_custom_call"``, and ``kernel_kind`` tells the
+kernels apart by the name the program gives each: the instruction is
+named after the kernel, ``<kind>_fwd`` or ``<kind>_bwd``, with XLA's
+``.N`` suffix (``%gru_bwd.6``), and its kind is that name without the
+suffix and the ending (``gru``). Under a transformation such as ``vmap``
+the name is wrapped (``%vmap_gae_fwd_.3``), and the wrapping goes too.
+A new kernel named so is found by its kind with no change here.
 """
 from __future__ import annotations
 
@@ -26,16 +28,11 @@ import glob
 import re
 
 START, END = "bench_window_start", "bench_window_end"
-_SHAPE = re.compile(r"\b(?:f32|bf16|f16|s32|u32|s8|u8|pred|f64|s64)"
-                    r"\[([0-9,]*)\]")
 _OPCODE = re.compile(r"^%\S+ = .*? ([a-z][a-z0-9\-]*)\(")
+_SUFFIX = re.compile(r"\.\d+$")
+_ENDING = re.compile(r"_(?:fwd|bwd)$")
 COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
                "reduce-scatter", "all-to-all")
-
-
-def _shapes(text: str):
-    return [tuple(int(d) for d in m.group(1).split(",") if d)
-            for m in _SHAPE.finditer(text)]
 
 
 def opcode(name: str) -> str:
@@ -44,24 +41,18 @@ def opcode(name: str) -> str:
 
 
 def kernel_kind(name: str):
-    """``"gae"``, ``"gru"`` or None for an ``XLA Ops`` event name."""
+    """The kind of the Pallas kernel an ``XLA Ops`` event runs, from the
+    instruction's name (``%gae_fwd.3 = ...`` gives ``"gae"``), or None
+    for an event that runs none."""
     if 'custom_call_target="tpu_custom_call"' not in name:
         return None
-    head, _, rest = name.partition(" custom-call(")
-    args = rest.split("), custom_call_target=")[0]
-    results, operands = _shapes(head), _shapes(args)
-    if len(operands) == 4 and len(results) == 1 and \
-            len(set(operands + results)) == 1:
-        return "gae"
-    if len(operands) == 2 and len(results) == 2 and \
-            len(set(operands + results)) == 1:
-        return "gae"
-    if len(operands) == 5 and len(results) == 1 and \
-            operands[0][-1] == 3 * results[0][-1]:
-        return "gru"
-    if len(operands) == 6 and len(results) == 4:
-        return "gru"
-    return "other"
+    instr = _SUFFIX.sub("", name.split(" = ", 1)[0].lstrip("%"))
+    # a kernel under a transformation is named ``<transform>(<name>)``,
+    # which XLA writes ``<transform>_<name>_``: under ``vmap``, the GAE
+    # kernel is ``vmap_gae_fwd_``
+    while instr.endswith("_") and "_" in instr[:-1]:
+        instr = instr.split("_", 1)[1][:-1]
+    return _ENDING.sub("", instr)
 
 
 def merged(intervals):

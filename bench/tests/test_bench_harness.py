@@ -14,6 +14,7 @@ import pytest
 import run
 import tiny
 from harness import catalog, correct, counts, job as job_mod, peaks, trace
+from harness.ref.backbones import gru
 from harness.ref.core import Ref
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -107,16 +108,16 @@ def test_kernel_counts_by_hand():
     assert counts.gae_fwd(16, 4) == (8 * 64, 4 * 5 * 64)
     # b=2, t=3, h=4: 6 row-steps of (6 h^2 + 14 h) flops; bytes: gi 3h,
     # reset flag, hs h per row-step, W_h, b_h and h0 once
-    assert counts.gru_fwd(2, 3, 4) == (6 * (6 * 16 + 56),
-                                       4 * (6 * 17 + 48 + 12 + 8))
-    assert counts.gru_bwd(2, 3, 4) == (6 * (12 * 16 + 120),
-                                       4 * (6 * 33 + 2 * 60 + 8))
-    ops = counts.gru_ops(tiny.gru_job(), Ref(tiny.gru_job()).info)
+    assert gru.gru_fwd(2, 3, 4) == (6 * (6 * 16 + 56),
+                                    4 * (6 * 17 + 48 + 12 + 8))
+    assert gru.gru_bwd(2, 3, 4) == (6 * (12 * 16 + 120),
+                                    4 * (6 * 33 + 2 * 60 + 8))
+    ops = counts.kernel_ops("gru", tiny.gru_job(), Ref(tiny.gru_job()).info)
     # policy: collect steps, (T + 1) x F rollout cells, PPO fwd + bwd
     # per minibatch, eval steps; AIP: T x F cells, 2 held-out CEs,
     # fwd + bwd per training minibatch
     assert len(ops) == 8 + 5 * 3 + 2 * 3 * 2 * 2 + 100 + 4 * 3 + 2 + 2 * 3
-    assert counts.gru_ops(tiny.job(), Ref(tiny.job()).info) == []
+    assert counts.kernel_ops("gru", tiny.job(), Ref(tiny.job()).info) == []
 
 
 def test_least_time_takes_the_larger_bound():
@@ -143,36 +144,39 @@ def test_opcode_and_kernel_kinds():
            "(s32[], f32[100,2]) %tuple), body=%b")
     assert trace.opcode(tup) == "while"
     cc = ', custom_call_target="tpu_custom_call", operand_layout_constraints'
-    gae = ("%k = f32[16,100]{1,0} custom-call(f32[16,100]{1,0} %a, "
+    gae = ("%gae_fwd.3 = f32[16,100]{1,0} custom-call(f32[16,100]{1,0} %a, "
            "f32[16,100]{1,0} %b, f32[16,100]{1,0} %c, f32[16,100]{1,0} %d)"
            + cc)
-    gru = ("%k = f32[16,8,64]{2,1,0} custom-call(f32[16,8,192]{2,1,0} %a, "
-           "f32[64,192]{1,0} %b, f32[1,192]{1,0} %c, f32[16,8,1]{2,1,0} %d, "
-           "f32[8,64]{1,0} %e)" + cc)
+    gru = ("%gru_bwd.6 = (f32[16,8,192]{2,1,0}, f32[64,192]{1,0}) "
+           "custom-call(f32[16,8,192]{2,1,0} %a, f32[64,192]{1,0} %b)" + cc)
+    # a made-up kernel with GAE's operands is its own kind, not GAE's
+    ssd = gae.replace("%gae_fwd.3", "%ssd_fwd.1")
     assert trace.kernel_kind(gae) == "gae"
     assert trace.kernel_kind(gru) == "gru"
+    assert trace.kernel_kind(ssd) == "ssd"
     assert trace.kernel_kind(gae.replace(cc, ", custom_call_target=\"x\""
                                          )) is None
 
 
 def test_recorded_trace_reduces():
-    """A trace recorded on a TPU v5e of one jitted GAE gradient (the GAE
-    backward kernel), one GRU-sequence gradient (forward and backward
-    kernels) and a matmul, between the harness's window marks."""
+    """A trace recorded on a TPU v5e (``record_trace.py``) of one jitted
+    GAE gradient (the GAE backward kernel, named
+    ``transpose_jvp_gae_bwd__``), one GRU-sequence gradient (``gru_fwd``
+    and ``gru_bwd``) and a matmul, between the harness's window marks."""
     from jax.profiler import ProfileData
     t = trace.reduce(ProfileData.from_file(str(DATA / "tiny.xplane.pb")))
     assert len(t.devices) == 1
     d = t.devices[0]
-    assert t.window_s == pytest.approx(3.13941e-3)
-    assert t.busy_s() == pytest.approx(2.1166e-5)
+    assert t.window_s == pytest.approx(2.535031e-3)
+    assert t.busy_s() == pytest.approx(2.1254e-5)
     assert d.kernel_events("gae") == 1 and d.kernel_events("gru") == 2
-    assert d.kernel_seconds("gae") == pytest.approx(1.42e-7)
-    assert d.kernel_seconds("gru") == pytest.approx(1.3374e-5)
-    assert d.module_seconds("jit__lambda") == pytest.approx(2.174e-5)
+    assert d.kernel_seconds("gae") == pytest.approx(1.41e-7)
+    assert d.kernel_seconds("gru") == pytest.approx(1.341e-5)
+    assert d.module_seconds("jit__lambda") == pytest.approx(2.1834e-5)
     assert d.collective_seconds() == 0.0
     b = t.breakdown()
     assert len(b["device_ops"]) == 10 and len(b["idle_gaps"]) <= 10
-    assert b["device_ops"][0][0] == "jit__lambda/transpose_jvp___.1"
+    assert b["device_ops"][0][0] == "jit__lambda/gru_bwd.1"
     assert sum(s for _, s in b["device_ops"]) <= t.busy_s() + 1e-12
     gaps = sum(s for _, s in b["idle_gaps"])
     assert gaps <= t.window_s - t.busy_s() + 1e-12
@@ -190,14 +194,18 @@ def test_readers_on_the_recorded_trace():
     t = trace.reduce(ProfileData.from_file(str(DATA / "tiny.xplane.pb")))
     view = _View(t, tiny.gru_job())
     idle = catalog.reader("device_idle_share")(view)
-    assert idle == pytest.approx(100 * (1 - 2.1166e-5 / 3.13941e-3))
+    assert idle == pytest.approx(100 * (1 - 2.1254e-5 / 2.535031e-3))
     least = sum(peaks.least_seconds(f, b, "TPU v5 lite")
                 for f, b in counts.gae_ops(view.job, view.info))
     assert catalog.reader("gae_roofline")(view) == \
-        pytest.approx(100 * least * 2 / 1.42e-7)
+        pytest.approx(100 * least * 2 / 1.41e-7)
+    least = sum(peaks.least_seconds(f, b, "TPU v5 lite")
+                for f, b in counts.kernel_ops("gru", view.job, view.info))
+    assert catalog.reader("gru_roofline")(view) == \
+        pytest.approx(100 * least * 2 / 1.341e-5)
     flops = counts.round_matmul_flops(view.job, view.info) * 2
     assert catalog.reader("round_mfu")(view) == \
-        pytest.approx(100 * flops / (3.13941e-3 * 197e12))
+        pytest.approx(100 * flops / (2.535031e-3 * 197e12))
     # programs that are not in the trace read nothing, not 0
     for name in ("collect_ms", "aip_round_ms", "inner_ms", "gs_eval_ms",
                  "collective_ms"):
